@@ -127,7 +127,7 @@ def env_header() -> dict:
             "backend": jax.default_backend(),
             "device_kind": getattr(dev, "device_kind", str(dev)),
             "device_count": jax.device_count(),
-            "pallas_interpret": not _ops.on_tpu(),
+            "pallas_interpret": _ops.pallas_interpret(),
             "python": platform.python_version(),
             "platform": platform.platform(),
         }

@@ -14,8 +14,11 @@ the planner's chosen plan against the monolithic call.
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m benchmarks.dist_sweep
 
-Run without the flag, it re-execs itself in a subprocess with 8 forced host
-devices (jax locks the device count at first init).
+Run with ``JAX_PLATFORMS=cpu`` and without the flag, it re-execs itself in
+a subprocess with 8 forced host devices (jax locks the device count at
+first init).  The decision is read from the environment before JAX is
+imported; on a host with real devices it never re-execs (the parent would
+hold the chips the child needs), and it needs 8 devices there.
 
 Emits ``results/BENCH_dist.json``.
 """
@@ -41,6 +44,15 @@ WORKLOADS = [
     ("balanced_t16_m4096", 16, 6, 4096, 19),
     ("tree_heavy_t64_m512", 64, 5, 512, 19),
 ]
+
+
+def _should_reexec() -> bool:
+    """Re-exec only a CPU-only run that lacks the forced device count."""
+    if os.environ.get(_CHILD_ENV):
+        return False
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        return False
+    return "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 
 
 def _reexec_with_devices() -> None:
@@ -216,17 +228,17 @@ def _sweep(iters: int, warmup: int) -> dict:
 
 
 def main(iters: int = 7, warmup: int = 2) -> dict | None:
-    import jax
-
-    if jax.device_count() < N_DEVICES:
-        if os.environ.get(_CHILD_ENV):
-            raise SystemExit(
-                f"forced host device count did not take effect "
-                f"({jax.device_count()} < {N_DEVICES})"
-            )
+    if _should_reexec():
         print(f"re-exec with {N_DEVICES} forced host devices ...")
         _reexec_with_devices()
         return None
+    import jax
+
+    if jax.device_count() < N_DEVICES:
+        raise SystemExit(
+            f"dist_sweep needs {N_DEVICES} devices, found {jax.device_count()} "
+            f"(JAX_PLATFORMS=cpu re-execs with forced host devices)"
+        )
     return _sweep(iters, warmup)
 
 

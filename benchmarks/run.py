@@ -70,6 +70,11 @@ def _run(name: str) -> None:
 
 
 def main() -> None:
+    import os
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     names = sys.argv[1:] or BENCHES
     for n in names:
         _run(n)

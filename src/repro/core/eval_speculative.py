@@ -91,7 +91,9 @@ def speculative_node_eval(
         if attr_select is None:
             n_attrs = records.shape[-1]
             attr_select = jax.nn.one_hot(attr_idx, n_attrs, dtype=records.dtype).T
-        vals = records @ attr_select  # (M, N)
+        # HIGHEST: on TPU the default f32 matmul is one bf16 pass, which
+        # would round the attribute values the one-hot product must copy
+        vals = jnp.dot(records, attr_select, precision=jax.lax.Precision.HIGHEST)  # (M, N)
     else:
         vals = records[:, attr_idx]  # (M, N) gather
     return child[None, :] + (vals > threshold[None, :]).astype(jnp.int32)

@@ -297,7 +297,6 @@ class CascadeEvaluator:
         default pallas on TPU, jnp elsewhere.
       algorithm / jump_mode / block_m: forwarded to the stage kernels.
       stages / calibration: used only when ``plan`` is None.
-      interpret: force Pallas interpret mode (pallas engine only).
     """
 
     def __init__(
@@ -313,7 +312,6 @@ class CascadeEvaluator:
         block_m: int | None = None,
         stages: int = 2,
         calibration=None,
-        interpret: bool | None = None,
         registry: obs.Registry | None = None,
         tracer: obs.Tracer | None = None,
     ):
@@ -331,7 +329,6 @@ class CascadeEvaluator:
         self.algorithm = algorithm
         self.jump_mode = jump_mode
         self.block_m = block_m
-        self.interpret = interpret
         if plan is None:
             plan = plan_cascade(
                 forest,
@@ -371,30 +368,30 @@ class CascadeEvaluator:
     # -- stage construction -------------------------------------------------
 
     def _build_stage(self, s: int) -> Callable:
+        """Stage ``s``'s vote program: a traceable function of a device
+        record tile (rows, A) returning its (rows, C) vote counts."""
         ids = self.plan.stage_trees(s)
         if self.engine == "pallas":
             # The packed tables depend on the record attribute count, which
             # EncodedForest does not store — pack lazily on first call.
             packed_by_a: dict[int, _ops.PackedForest] = {}
 
-            def run(rec: np.ndarray) -> np.ndarray:
+            def votes(rec: jax.Array) -> jax.Array:
                 a = rec.shape[1]
                 packed = packed_by_a.get(a)
                 if packed is None:
                     packed = _ops.PackedForest(_StageForest(self.forest, ids), a)
                     packed_by_a[a] = packed
-                out = _ops.forest_votes_fused(
-                    jnp.asarray(rec),
+                return _ops.forest_votes_fused(
+                    rec,
                     packed,
                     n_classes=self._c,
                     algorithm=self.algorithm,
                     jump_mode=self.jump_mode,
                     block_m=self.block_m,
-                    interpret=self.interpret,
                 )
-                return np.asarray(jax.block_until_ready(out))
 
-            return run
+            return votes
 
         idx = list(ids)
         tables = (
@@ -405,18 +402,17 @@ class CascadeEvaluator:
         )
         max_depth = int(self.forest.max_depth)
 
-        def run(rec: np.ndarray) -> np.ndarray:
-            out = _votes_jnp(
-                jnp.asarray(rec),
+        def votes(rec: jax.Array) -> jax.Array:
+            return _votes_jnp(
+                rec,
                 *tables,
                 max_depth=max_depth,
                 n_classes=self._c,
                 algorithm=self.algorithm,
                 jump_mode=self.jump_mode,
             )
-            return np.asarray(jax.block_until_ready(out))
 
-        return run
+        return votes
 
     def _stage_votes(self, s: int, rec: np.ndarray) -> tuple[np.ndarray, int]:
         """Run stage ``s`` on a dense record tile; returns (votes, pad_rows)."""
@@ -429,7 +425,8 @@ class CascadeEvaluator:
         t0 = time.perf_counter()
         with self.tracer.span("cascade.stage", cat="cascade", stage=s,
                               survivors=n, rows=rows):
-            votes = self._stages[s](rec)[:n]
+            votes = np.asarray(jax.block_until_ready(
+                self._stages[s](jnp.asarray(rec))))[:n]
         ms = (time.perf_counter() - t0) * 1e3
         self.m_stage_ms.labels(stage=s).observe(ms)
         key = (s, rows)
@@ -633,7 +630,6 @@ def _builder(engine: str, algorithm: str, jump_mode: str) -> Callable:
         bound: float | None = 1.0,
         block_m: int | None = None,
         calibration=None,
-        interpret: bool | None = None,
         registry: obs.Registry | None = None,
         tracer: obs.Tracer | None = None,
     ) -> CascadeEvaluator:
@@ -648,7 +644,6 @@ def _builder(engine: str, algorithm: str, jump_mode: str) -> Callable:
             block_m=block_m,
             stages=stages,
             calibration=calibration,
-            interpret=interpret,
             registry=registry,
             tracer=tracer,
         )

@@ -3,38 +3,44 @@
 Two kernels mirror the paper's two parallel decompositions, re-tiled for the
 TPU memory hierarchy (HBM → VMEM → VREG) and compute units (MXU/VPU):
 
-``speculative_kernel``  (paper Procedure 4/5, EvalTreeByNode)
+``speculative``  (paper Procedure 4/5, EvalTreeByNode)
     Records ride the sublane axis, tree nodes ride the 128-lane axis.
     Node evaluation is a single MXU matmul ``vals = records @ attr_select``
     (the one-hot selection matrix replaces the CUDA shared-memory gather),
     followed by a branch-free successor computation and ``⌈log₂ d⌉`` pointer
     jumps.  Jumps come in two flavours:
-      * ``gather``  — ``jnp.take_along_axis`` along lanes (Mosaic dynamic
-        gather; cheapest when supported),
+      * ``gather``  — ``path[i] ← path[path[i]]`` as lane gathers, one per
+        (destination, source) pair of 128-lane slices (Mosaic gathers only
+        within one vreg row, see :func:`_lane_take`),
       * ``onehot``  — batched permutation matmul ``pathᵢ₊₁ = P · pathᵢ``,
         all-MXU, no cross-lane gathers at all (the fully systolic variant).
 
-``data_parallel_kernel`` (paper Procedure 3, EvalTreeBySample)
-    One record per sublane; ``max_depth`` dependent rounds of table gathers.
+``data_parallel`` (paper Procedure 3, EvalTreeBySample)
+    One record per sublane; ``max_depth`` dependent rounds of table reads.
     This is the faithful TPU port of the data decomposition and exists to
     reproduce the paper's comparison: its inner loop is *serially dependent*
     (length d) whereas the speculative kernel needs only log₂ d dependent
     steps after one matmul.
 
-Both kernels tile records into ``block_m`` chunks over a 1-D grid; the tree
-tables use broadcast BlockSpecs (index_map → block 0) so they are DMA'd into
-VMEM once and reused across grid steps — the analogue of the paper's constant
-memory.  All shapes are padded by ``ops.py`` so that M % block_m == 0,
-N % 128 == 0 and A % 128 == 0 (MXU alignment).
+Every kernel is a forest kernel: tree tables are stacked along a leading
+tree axis and the grid is ``(M/block_m, T)`` with trees innermost, so each
+record tile stays resident in VMEM while the T tree tables stream past it.
+A single tree is the T = 1 case.  The per-tree scalar tables are stored as
+``(T, 1, N)`` so that their ``(1, 1, N)`` blocks meet the TPU tiling rule
+(the last two block dims must equal the array's or be multiples of
+(8, 128)); ``attr_select`` is ``(T, A, N)`` with ``(1, A, N)`` blocks.  All
+shapes are padded by ``ops.py`` so that M % block_m == 0, N % 128 == 0 and
+A % 128 == 0 (MXU alignment).
 
-``fused_speculative_pallas`` / ``fused_data_parallel_pallas`` lift the same
-bodies to a whole *forest* in one launch: tree tables are stacked to (T, N)
-(attr-select to (T, A, N)) and the grid gains a tree axis —
-``(M/block_m, T)`` with trees innermost, so each record tile stays resident
-in VMEM while the T tree tables stream past it.  One launch replaces the T
-separate launches of the per-tree path, which is where the fused forest
-variant wins: the per-launch overhead is paid once and the record DMA is
-amortised across the forest.
+Each kernel has one of two epilogues: per-tree classes ``(T, M, 1)``, or
+the per-record vote histogram ``(M, C)`` accumulated across the tree axis
+(the cascade's stage primitive).  The quantized layouts read int8/int16
+indices and bf16/f16 thresholds instead of ``attr_select`` and upcast in
+registers, so their results are bit-identical to the f32 kernels; the
+quantized speculative kernel builds its one-hot selection in VMEM.
+
+Interpret mode is chosen by the caller (``ops.pallas_interpret``); no
+kernel here defaults it.
 """
 
 from __future__ import annotations
@@ -45,297 +51,137 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANE = 128
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 # ---------------------------------------------------------------------------
-# helpers
+# lane reads
 # ---------------------------------------------------------------------------
 
 
-def _lane_gather(table_row: jax.Array, idx: jax.Array) -> jax.Array:
-    """``table_row`` (1, N) gathered at ``idx`` (BM, K) → (BM, K)."""
-    bm = idx.shape[0]
-    table = jnp.broadcast_to(table_row, (bm, table_row.shape[-1]))
-    return jnp.take_along_axis(table, idx, axis=1)
+def _select_lane(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[b, idx[b]]`` for ``table`` (BM or 1, K) and ``idx`` (BM, 1).
 
-
-def _onehot_matvec(idx: jax.Array, table_row: jax.Array, dtype=jnp.float32) -> jax.Array:
-    """Gather-free table lookup: ``onehot(idx) @ table`` on the MXU.
-
-    idx (BM, K) int32, table_row (1, N) → (BM, K) values of table[idx].
-    Built for the TPU path where cross-lane dynamic gathers are slow or
-    unsupported; numerically exact for int32 tables ≤ 2^24 (float32 mantissa).
+    A mask and a lane sum rather than a gather: Mosaic lowers a lane gather
+    only when the index has the table's shape.  Exactly one lane survives
+    the mask, so the sum returns it bit for bit (x + 0 = x).
     """
-    n = table_row.shape[-1]
-    oh = jax.nn.one_hot(idx, n, dtype=dtype)             # (BM, K, N)
-    return jnp.einsum("bkn,n->bk", oh, table_row[0].astype(dtype))
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], table.shape[-1]), 1)
+    return jnp.sum(jnp.where(lanes == idx, table, 0), axis=1, keepdims=True)
+
+
+def _lane_take(src: jax.Array, idx: jax.Array) -> jax.Array:
+    """``out[b, l] = src[b, idx[b, l]]`` for ``src`` (BM, K), ``idx`` (BM, L).
+
+    Mosaic gathers within one 128-lane vreg only, so each 128-lane slice of
+    the output gathers from every 128-lane slice of the source and keeps
+    the lanes whose index falls in that slice: (L/128)·(K/128) gathers of
+    the same (BM, 128) shape.  K and L are multiples of 128.
+    """
+    lo, hi = idx & (LANE - 1), idx >> 7
+    out = []
+    for o in range(0, idx.shape[-1], LANE):
+        lo_o, hi_o = lo[:, o:o + LANE], hi[:, o:o + LANE]
+        acc = None
+        for c in range(0, src.shape[-1], LANE):
+            part = jnp.take_along_axis(src[:, c:c + LANE], lo_o, axis=1)
+            acc = part if acc is None else jnp.where(hi_o == c // LANE, part, acc)
+        out.append(acc)
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
 # ---------------------------------------------------------------------------
-# speculative kernel (Procedure 4/5)
+# per-tree bodies on VMEM-resident arrays; each returns (BM, 1) int32 classes
 # ---------------------------------------------------------------------------
 
 
-def _speculative_compute(
-    rec,        # (BM, A) f32
-    sel,        # (A, N) f32 one-hot attribute selection
-    thr,        # (1, N) f32
-    child,      # (1, N) i32
-    class_val,  # (1, N) i32
-    *,
-    total_jumps: int,
-    jump_mode: str,
-):
-    """Procedure 4/5 core on VMEM-resident arrays; returns (BM, 1) int32."""
-    # --- node evaluation: every node, every record, one MXU matmul ---
-    vals = jnp.dot(rec, sel, preferred_element_type=jnp.float32)   # (BM, N)
-    pred = (vals > thr).astype(jnp.int32)
-    path = child + pred                                            # (BM, N)
-
-    # --- pointer jumping: path[i] ← path[path[i]] ---
+def _jump(path: jax.Array, total_jumps: int, jump_mode: str) -> jax.Array:
+    """Pointer jumping ``path[i] ← path[path[i]]`` (Procedure 4 l.15)."""
     if jump_mode == "gather":
         for _ in range(total_jumps):
-            path = jnp.take_along_axis(path, path, axis=1)
-    elif jump_mode == "onehot":
+            path = _lane_take(path, path)
+        return path
+    if jump_mode == "onehot":
         n = path.shape[-1]
         pathf = path.astype(jnp.float32)
         for _ in range(total_jumps):
             onehot = jax.nn.one_hot(path, n, dtype=jnp.float32)    # (BM, N, N)
-            pathf = jnp.einsum("bin,bn->bi", onehot, pathf)        # MXU
+            pathf = jnp.einsum("bin,bn->bi", onehot, pathf, precision=_HIGHEST)
             path = pathf.astype(jnp.int32)
-    else:
-        raise ValueError(f"unknown jump_mode {jump_mode!r}")
-
-    # --- root's eventual successor is the terminal leaf; read its class ---
-    root_leaf = path[:, 0:1]                                       # (BM, 1)
-    if jump_mode == "gather":
-        return _lane_gather(class_val, root_leaf)
-    return _onehot_matvec(root_leaf, class_val).astype(jnp.int32)
+        return path
+    raise ValueError(f"unknown jump_mode {jump_mode!r}")
 
 
-def _speculative_body(
-    records_ref,      # (BM, A) VMEM
-    attr_sel_ref,     # (A, N) VMEM — one-hot attribute selection
-    threshold_ref,    # (1, N) VMEM
-    child_ref,        # (1, N) VMEM
-    class_val_ref,    # (1, N) VMEM
-    out_ref,          # (BM, 1) VMEM
-    *,
-    total_jumps: int,
-    jump_mode: str,
-):
-    out_ref[...] = _speculative_compute(
-        records_ref[...].astype(jnp.float32),
-        attr_sel_ref[...].astype(jnp.float32),
-        threshold_ref[...],
-        child_ref[...],
-        class_val_ref[...],
-        total_jumps=total_jumps,
-        jump_mode=jump_mode,
-    )
+def _speculative(rec, sel, thr, child, class_val, *, total_jumps: int, jump_mode: str):
+    """Procedure 4/5: every node for every record, then pointer jumps.
+
+    rec (BM, A) f32; sel (A, N) one-hot; thr/child/class_val (1, N).
+    """
+    # HIGHEST keeps the one-hot product exact (a single bf16 pass would
+    # round the attribute values)
+    vals = jnp.dot(rec, sel.astype(jnp.float32), precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)             # (BM, N)
+    path = child + (vals > thr).astype(jnp.int32)                  # (BM, N)
+    path = _jump(path, total_jumps, jump_mode)
+    # the root's eventual successor is the terminal leaf; read its class
+    return _select_lane(class_val, path[:, 0:1])
 
 
-def speculative_pallas(
-    records: jax.Array,     # (M, A) — padded
-    attr_select: jax.Array, # (A, N) — padded one-hot
-    threshold: jax.Array,   # (1, N)
-    child: jax.Array,       # (1, N)
-    class_val: jax.Array,   # (1, N)
-    *,
-    total_jumps: int,
-    block_m: int,
-    jump_mode: str = "gather",
-    interpret: bool = True,
-) -> jax.Array:
-    """Launch the speculative kernel over a 1-D record grid. Returns (M, 1)."""
-    m, a = records.shape
-    n = threshold.shape[-1]
-    assert m % block_m == 0, (m, block_m)
-    grid = (m // block_m,)
-    kernel = functools.partial(
-        _speculative_body, total_jumps=total_jumps, jump_mode=jump_mode
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, a), lambda i: (i, 0)),  # records: stream tiles
-            pl.BlockSpec((a, n), lambda i: (0, 0)),        # tree tables: broadcast
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
-        interpret=interpret,
-    )(records, attr_select, threshold, child, class_val)
+def _speculative_q(rec, attr_idx, thr, child, class_val, *, total_jumps: int):
+    """Procedure 4/5 on quantized tables: the one-hot selection matrix is
+    built in VMEM from ``attr_idx`` (A·N compares) instead of being stored."""
+    sel = jax.lax.broadcasted_iota(jnp.int32, (rec.shape[1], attr_idx.shape[-1]), 0)
+    sel = (sel == attr_idx.astype(jnp.int32)).astype(jnp.float32)
+    return _speculative(rec, sel, thr.astype(jnp.float32), child.astype(jnp.int32),
+                        class_val.astype(jnp.int32),
+                        total_jumps=total_jumps, jump_mode="gather")
 
 
-# ---------------------------------------------------------------------------
-# data-parallel kernel (Procedure 3)
-# ---------------------------------------------------------------------------
+def _data_parallel(rec, attr_idx, thr, child, class_val, *, max_depth: int):
+    """Procedure 3: ``max_depth`` dependent descents, one record per row.
 
-
-def _data_parallel_compute(
-    rec,        # (BM, A) f32
-    attr_idx,   # (1, N) i32
-    thr,        # (1, N) f32
-    child,      # (1, N) i32
-    class_val,  # (1, N) i32
-    *,
-    max_depth: int,
-):
-    """Procedure 3 core on VMEM-resident arrays; returns (BM, 1) int32."""
-    bm = rec.shape[0]
-    idx = jnp.zeros((bm, 1), jnp.int32)
+    Tables (1, N) of any int/float storage dtype; upcast in registers.
+    """
+    attr_idx = attr_idx.astype(jnp.int32)
+    thr = thr.astype(jnp.float32)
+    child = child.astype(jnp.int32)
+    idx = jnp.zeros((rec.shape[0], 1), jnp.int32)
     for _ in range(max_depth):
-        a = _lane_gather(attr_idx, idx)                   # (BM, 1)
-        t = _lane_gather(thr, idx)
-        c = _lane_gather(child, idx)
-        v = jnp.take_along_axis(rec, a, axis=1)           # per-record attr
+        a = _select_lane(attr_idx, idx)
+        t = _select_lane(thr, idx)
+        c = _select_lane(child, idx)
+        v = _select_lane(rec, a)                                   # per-record attr
         idx = c + (v > t).astype(jnp.int32)
-    return _lane_gather(class_val, idx)
+    return _select_lane(class_val.astype(jnp.int32), idx)
 
 
-def _data_parallel_body(
-    records_ref,      # (BM, A) VMEM
-    attr_idx_ref,     # (1, N) VMEM (int32)
-    threshold_ref,    # (1, N) VMEM
-    child_ref,        # (1, N) VMEM
-    class_val_ref,    # (1, N) VMEM
-    out_ref,          # (BM, 1)
-    *,
-    max_depth: int,
-):
-    out_ref[...] = _data_parallel_compute(
-        records_ref[...].astype(jnp.float32),
-        attr_idx_ref[...],
-        threshold_ref[...],
-        child_ref[...],
-        class_val_ref[...],
-        max_depth=max_depth,
-    )
-
-
-def data_parallel_pallas(
-    records: jax.Array,    # (M, A) padded
-    attr_idx: jax.Array,   # (1, N)
-    threshold: jax.Array,  # (1, N)
-    child: jax.Array,      # (1, N)
-    class_val: jax.Array,  # (1, N)
-    *,
-    max_depth: int,
-    block_m: int,
-    interpret: bool = True,
-) -> jax.Array:
-    m, a = records.shape
-    n = threshold.shape[-1]
-    assert m % block_m == 0, (m, block_m)
-    grid = (m // block_m,)
-    kernel = functools.partial(_data_parallel_body, max_depth=max_depth)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, a), lambda i: (i, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
-        interpret=interpret,
-    )(records, attr_idx, threshold, child, class_val)
+_COMPUTE = {
+    ("speculative", "f32"): _speculative,
+    ("data_parallel", "f32"): _data_parallel,
+    ("speculative", "quant"): _speculative_q,
+    ("data_parallel", "quant"): _data_parallel,
+}
 
 
 # ---------------------------------------------------------------------------
-# fused stacked-forest kernels (one launch for T trees)
+# one launcher for every kernel
 # ---------------------------------------------------------------------------
-#
-# Grid (M/block_m, T): the record-tile axis is outer and the tree axis inner,
-# so consecutive grid steps revisit the same record block (no re-DMA) while
-# the (1, N)-blocked tree tables stream through VMEM one tree at a time.
-# Output lands as (T, M, 1) blocks of (1, BM, 1) — the trailing singleton
-# keeps the write a pure leading-axis expand of the per-tree (BM, 1) result,
-# no cross-lane relayout.
 
 
-def _fused_speculative_body(
-    records_ref,      # (BM, A) VMEM — shared across the tree axis
-    attr_sel_ref,     # (1, A, N) VMEM — tree t's one-hot selection
-    threshold_ref,    # (1, N) VMEM
-    child_ref,        # (1, N) VMEM
-    class_val_ref,    # (1, N) VMEM
-    out_ref,          # (1, BM, 1) VMEM
-    *,
-    total_jumps: int,
-    jump_mode: str,
-):
-    out_ref[...] = _speculative_compute(
-        records_ref[...].astype(jnp.float32),
-        attr_sel_ref[0].astype(jnp.float32),
-        threshold_ref[...],
-        child_ref[...],
-        class_val_ref[...],
-        total_jumps=total_jumps,
-        jump_mode=jump_mode,
-    )[None]
+def _classes_body(compute, records_ref, *refs):
+    *table_refs, out_ref = refs
+    out_ref[0] = compute(records_ref[...].astype(jnp.float32),
+                         *(r[0] for r in table_refs))
 
 
-def fused_speculative_pallas(
-    records: jax.Array,     # (M, A) — padded
-    attr_select: jax.Array, # (T, A, N) — per-tree padded one-hot
-    threshold: jax.Array,   # (T, N)
-    child: jax.Array,       # (T, N)
-    class_val: jax.Array,   # (T, N)
-    *,
-    total_jumps: int,
-    block_m: int,
-    jump_mode: str = "gather",
-    interpret: bool = True,
-) -> jax.Array:
-    """One speculative launch over the whole forest. Returns (T, M, 1)."""
-    m, a = records.shape
-    t, n = threshold.shape
-    assert m % block_m == 0, (m, block_m)
-    grid = (m // block_m, t)
-    kernel = functools.partial(
-        _fused_speculative_body, total_jumps=total_jumps, jump_mode=jump_mode
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, a), lambda i, j: (i, 0)),   # record tile: VMEM-resident per i
-            pl.BlockSpec((1, a, n), lambda i, j: (j, 0, 0)),   # tree tables: stream over j
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_m, 1), lambda i, j: (j, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, m, 1), jnp.int32),
-        interpret=interpret,
-    )(records, attr_select, threshold, child, class_val)
-
-
-# ---------------------------------------------------------------------------
-# fused vote-accumulating kernels (cascade stages)
-# ---------------------------------------------------------------------------
-#
-# Same grid as the fused class kernels — (M/block_m, T) with trees innermost —
-# but instead of materialising the (T, M) per-tree class matrix the output is
-# the (M, C) per-record *vote histogram*: the output BlockSpec's index map
-# ignores the tree axis, so every tree-step of one record tile revisits the
-# same (BM, C) VMEM block and accumulates its one-hot vote into it
-# (initialised at j == 0).  The per-tree classes never leave VMEM, which is
-# what makes the cascade's margin bookkeeping free of a (T, M) round trip.
-
-
-def _accumulate_votes(out_ref, cls):
-    """Add one tree's one-hot votes for ``cls`` (BM, 1) into ``out_ref``."""
+def _votes_body(compute, records_ref, *refs):
+    """Add one tree's one-hot vote into the (BM, C) tile revisited across
+    the tree axis (initialised at the first tree)."""
+    *table_refs, out_ref = refs
+    cls = compute(records_ref[...].astype(jnp.float32), *(r[0] for r in table_refs))
     bm, c = out_ref.shape
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (bm, c), 1)
-    votes = (lanes == cls).astype(jnp.int32)                       # (BM, C)
+    votes = (jax.lax.broadcasted_iota(jnp.int32, (bm, c), 1) == cls).astype(jnp.int32)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -347,302 +193,67 @@ def _accumulate_votes(out_ref, cls):
         out_ref[...] += votes
 
 
-def _fused_votes_speculative_body(
-    records_ref,      # (BM, A) VMEM — shared across the tree axis
-    attr_sel_ref,     # (1, A, N) VMEM
-    threshold_ref,    # (1, N) VMEM
-    child_ref,        # (1, N) VMEM
-    class_val_ref,    # (1, N) VMEM
-    out_ref,          # (BM, C) VMEM — revisited across the tree axis
+def forest_pallas(
+    records: jax.Array,
+    tables: tuple[jax.Array, ...],
     *,
-    total_jumps: int,
-    jump_mode: str,
-):
-    cls = _speculative_compute(
-        records_ref[...].astype(jnp.float32),
-        attr_sel_ref[0].astype(jnp.float32),
-        threshold_ref[...],
-        child_ref[...],
-        class_val_ref[...],
-        total_jumps=total_jumps,
-        jump_mode=jump_mode,
-    )
-    _accumulate_votes(out_ref, cls)
-
-
-def fused_votes_speculative_pallas(
-    records: jax.Array,     # (M, A) — padded
-    attr_select: jax.Array, # (T, A, N) — per-tree padded one-hot
-    threshold: jax.Array,   # (T, N)
-    child: jax.Array,       # (T, N)
-    class_val: jax.Array,   # (T, N)
-    *,
-    n_classes: int,         # padded class-lane count C
-    total_jumps: int,
+    algorithm: str,
+    layout: str = "f32",
     block_m: int,
+    interpret: bool,
+    n_classes: int | None = None,
+    total_jumps: int = 1,
     jump_mode: str = "gather",
-    interpret: bool = True,
+    max_depth: int = 1,
 ) -> jax.Array:
-    """One speculative launch accumulating forest votes. Returns (M, C)."""
+    """Launch one tree-evaluation kernel over a stacked forest.
+
+    Args:
+      records: (M, A) padded records (any float dtype; compared in f32).
+      tables: the per-tree tables, leading tree axis T.  Speculative f32:
+        ``(attr_select (T, A, N), threshold, child, class_val)``; every
+        other kernel: ``(attr_idx, threshold, child, class_val)``.  Scalar
+        tables are (T, N), viewed here as (T, 1, N).
+      algorithm: "speculative" (Procedure 4/5) or "data_parallel" (Proc. 3).
+      layout: "f32" or "quant" (storage-dtype tables, no ``attr_select``).
+      block_m: records per tile; divides M.
+      interpret: run the Pallas interpreter (CPU backend) or compile.
+      n_classes: None for the per-tree class epilogue, else the padded vote
+        width C of the vote epilogue.
+      total_jumps / jump_mode: speculative pointer-jump schedule.
+      max_depth: data-parallel descent rounds.
+
+    Returns:
+      (T, M, 1) int32 per-tree classes, or (M, C) int32 vote counts.
+    """
     m, a = records.shape
-    t, n = threshold.shape
     assert m % block_m == 0, (m, block_m)
-    grid = (m // block_m, t)
-    kernel = functools.partial(
-        _fused_votes_speculative_body, total_jumps=total_jumps, jump_mode=jump_mode
-    )
+    tables = tuple(x if x.ndim == 3 else x[:, None, :] for x in tables)
+    t = tables[0].shape[0]
+    if algorithm == "speculative":
+        kw = {"total_jumps": total_jumps}
+        if layout == "f32":
+            kw["jump_mode"] = jump_mode
+    elif algorithm == "data_parallel":
+        kw = {"max_depth": max_depth}
+    else:
+        raise ValueError(f"unknown algorithm {algorithm!r}")
+    compute = functools.partial(_COMPUTE[(algorithm, layout)], **kw)
+    if n_classes is None:
+        body = functools.partial(_classes_body, compute)
+        out_spec = pl.BlockSpec((1, block_m, 1), lambda i, j: (j, i, 0))
+        out_shape = jax.ShapeDtypeStruct((t, m, 1), jnp.int32)
+    else:
+        body = functools.partial(_votes_body, compute)
+        out_spec = pl.BlockSpec((block_m, n_classes), lambda i, j: (i, 0))
+        out_shape = jax.ShapeDtypeStruct((m, n_classes), jnp.int32)
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, a), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, a, n), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_m, n_classes), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n_classes), jnp.int32),
+        body,
+        grid=(m // block_m, t),
+        in_specs=[pl.BlockSpec((block_m, a), lambda i, j: (i, 0))]   # record tile resident
+        + [pl.BlockSpec((1,) + x.shape[1:], lambda i, j: (j, 0, 0))  # tree tables stream
+           for x in tables],
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(records, attr_select, threshold, child, class_val)
-
-
-def _fused_votes_data_parallel_body(
-    records_ref,      # (BM, A) VMEM
-    attr_idx_ref,     # (1, N) VMEM (int32)
-    threshold_ref,    # (1, N) VMEM
-    child_ref,        # (1, N) VMEM
-    class_val_ref,    # (1, N) VMEM
-    out_ref,          # (BM, C) VMEM — revisited across the tree axis
-    *,
-    max_depth: int,
-):
-    cls = _data_parallel_compute(
-        records_ref[...].astype(jnp.float32),
-        attr_idx_ref[...],
-        threshold_ref[...],
-        child_ref[...],
-        class_val_ref[...],
-        max_depth=max_depth,
-    )
-    _accumulate_votes(out_ref, cls)
-
-
-def fused_votes_data_parallel_pallas(
-    records: jax.Array,    # (M, A) padded
-    attr_idx: jax.Array,   # (T, N)
-    threshold: jax.Array,  # (T, N)
-    child: jax.Array,      # (T, N)
-    class_val: jax.Array,  # (T, N)
-    *,
-    n_classes: int,        # padded class-lane count C
-    max_depth: int,
-    block_m: int,
-    interpret: bool = True,
-) -> jax.Array:
-    """One data-parallel launch accumulating forest votes. Returns (M, C)."""
-    m, a = records.shape
-    t, n = threshold.shape
-    assert m % block_m == 0, (m, block_m)
-    grid = (m // block_m, t)
-    kernel = functools.partial(_fused_votes_data_parallel_body, max_depth=max_depth)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, a), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_m, n_classes), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n_classes), jnp.int32),
-        interpret=interpret,
-    )(records, attr_idx, threshold, child, class_val)
-
-
-# ---------------------------------------------------------------------------
-# quantized fused kernels (compact SoA layouts, §4 memory optimizations)
-# ---------------------------------------------------------------------------
-#
-# Same grid as the f32 fused kernels — (M/block_m, T), trees innermost — but
-# the tables arrive at their quantized storage dtypes (int8/int16 indices,
-# bf16/f16/f32 thresholds) and there is **no attr_select matrix**: node
-# evaluation gathers each record's attribute directly,
-# ``vals[b, n] = rec[b, attr_idx[n]]``, which is what makes the quantized
-# node table 1–2 orders of magnitude smaller than the one-hot layout.  All
-# arithmetic upcasts at the register level (int → int32, float → f32), so
-# results are bit-identical to the f32 kernels running on the same
-# (possibly quantized) threshold values.
-
-
-def _quant_speculative_compute(
-    rec,        # (BM, A) f32
-    attr_idx,   # (1, N) int8/int16/int32
-    thr,        # (1, N) bf16/f16/f32
-    child,      # (1, N) int16/int32
-    class_val,  # (1, N) int8/int16/int32
-    *,
-    total_jumps: int,
-):
-    """Procedure 4/5 core on quantized tables; returns (BM, 1) int32."""
-    bm = rec.shape[0]
-    n = attr_idx.shape[-1]
-    idx = jnp.broadcast_to(attr_idx.astype(jnp.int32), (bm, n))
-    vals = jnp.take_along_axis(rec, idx, axis=1)              # (BM, N) gather
-    pred = (vals > thr.astype(jnp.float32)).astype(jnp.int32)
-    path = child.astype(jnp.int32) + pred                      # (BM, N)
-    for _ in range(total_jumps):
-        path = jnp.take_along_axis(path, path, axis=1)
-    return _lane_gather(class_val.astype(jnp.int32), path[:, 0:1])
-
-
-def _fused_speculative_q_body(
-    records_ref,      # (BM, A) VMEM — shared across the tree axis
-    attr_idx_ref,     # (1, N) VMEM (int8/int16)
-    threshold_ref,    # (1, N) VMEM (bf16/f16/f32)
-    child_ref,        # (1, N) VMEM (int16/int32)
-    class_val_ref,    # (1, N) VMEM (int8/int16)
-    out_ref,          # (1, BM, 1) VMEM
-    *,
-    total_jumps: int,
-):
-    out_ref[...] = _quant_speculative_compute(
-        records_ref[...].astype(jnp.float32),
-        attr_idx_ref[...],
-        threshold_ref[...],
-        child_ref[...],
-        class_val_ref[...],
-        total_jumps=total_jumps,
-    )[None]
-
-
-def _fused_data_parallel_q_body(
-    records_ref,      # (BM, A) VMEM
-    attr_idx_ref,     # (1, N) VMEM (int8/int16)
-    threshold_ref,    # (1, N) VMEM (bf16/f16/f32)
-    child_ref,        # (1, N) VMEM (int16/int32)
-    class_val_ref,    # (1, N) VMEM (int8/int16)
-    out_ref,          # (1, BM, 1)
-    *,
-    max_depth: int,
-):
-    out_ref[...] = _data_parallel_compute(
-        records_ref[...].astype(jnp.float32),
-        attr_idx_ref[...].astype(jnp.int32),
-        threshold_ref[...].astype(jnp.float32),
-        child_ref[...].astype(jnp.int32),
-        class_val_ref[...].astype(jnp.int32),
-        max_depth=max_depth,
-    )[None]
-
-
-def _fused_q_pallas(kernel, records, attr_idx, threshold, child, class_val,
-                    *, block_m, interpret):
-    """Shared launch plumbing for the quantized fused kernels."""
-    m, a = records.shape
-    t, n = threshold.shape
-    assert m % block_m == 0, (m, block_m)
-    grid = (m // block_m, t)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, a), lambda i, j: (i, 0)),  # record tile resident
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),        # quant tables stream
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_m, 1), lambda i, j: (j, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, m, 1), jnp.int32),
-        interpret=interpret,
-    )(records, attr_idx, threshold, child, class_val)
-
-
-def fused_speculative_q_pallas(
-    records: jax.Array,    # (M, A) padded f32
-    attr_idx: jax.Array,   # (T, N) int8/int16
-    threshold: jax.Array,  # (T, N) bf16/f16/f32
-    child: jax.Array,      # (T, N) int16/int32
-    class_val: jax.Array,  # (T, N) int8/int16
-    *,
-    total_jumps: int,
-    block_m: int,
-    interpret: bool = True,
-) -> jax.Array:
-    """Quantized speculative launch over the whole forest. Returns (T, M, 1)."""
-    kernel = functools.partial(_fused_speculative_q_body, total_jumps=total_jumps)
-    return _fused_q_pallas(kernel, records, attr_idx, threshold, child, class_val,
-                           block_m=block_m, interpret=interpret)
-
-
-def fused_data_parallel_q_pallas(
-    records: jax.Array,    # (M, A) padded f32
-    attr_idx: jax.Array,   # (T, N) int8/int16
-    threshold: jax.Array,  # (T, N) bf16/f16/f32
-    child: jax.Array,      # (T, N) int16/int32
-    class_val: jax.Array,  # (T, N) int8/int16
-    *,
-    max_depth: int,
-    block_m: int,
-    interpret: bool = True,
-) -> jax.Array:
-    """Quantized data-parallel launch over the whole forest. Returns (T, M, 1)."""
-    kernel = functools.partial(_fused_data_parallel_q_body, max_depth=max_depth)
-    return _fused_q_pallas(kernel, records, attr_idx, threshold, child, class_val,
-                           block_m=block_m, interpret=interpret)
-
-
-def _fused_data_parallel_body(
-    records_ref,      # (BM, A) VMEM
-    attr_idx_ref,     # (1, N) VMEM (int32)
-    threshold_ref,    # (1, N) VMEM
-    child_ref,        # (1, N) VMEM
-    class_val_ref,    # (1, N) VMEM
-    out_ref,          # (1, BM, 1)
-    *,
-    max_depth: int,
-):
-    out_ref[...] = _data_parallel_compute(
-        records_ref[...].astype(jnp.float32),
-        attr_idx_ref[...],
-        threshold_ref[...],
-        child_ref[...],
-        class_val_ref[...],
-        max_depth=max_depth,
-    )[None]
-
-
-def fused_data_parallel_pallas(
-    records: jax.Array,    # (M, A) padded
-    attr_idx: jax.Array,   # (T, N)
-    threshold: jax.Array,  # (T, N)
-    child: jax.Array,      # (T, N)
-    class_val: jax.Array,  # (T, N)
-    *,
-    max_depth: int,
-    block_m: int,
-    interpret: bool = True,
-) -> jax.Array:
-    """One data-parallel launch over the whole forest. Returns (T, M, 1)."""
-    m, a = records.shape
-    t, n = threshold.shape
-    assert m % block_m == 0, (m, block_m)
-    grid = (m // block_m, t)
-    kernel = functools.partial(_fused_data_parallel_body, max_depth=max_depth)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, a), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_m, 1), lambda i, j: (j, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, m, 1), jnp.int32),
-        interpret=interpret,
-    )(records, attr_idx, threshold, child, class_val)
+    )(records, *tables)

@@ -2,8 +2,9 @@
 
 Handles everything the raw kernels assume away: lane/sublane padding of the
 tree and record arrays, VMEM-budget-driven block-size selection, phantom-node
-padding (the paper's half-warp phantom generalised to 128-lane tiles),
-interpret-mode fallback off-TPU, and unpadding of results.
+padding (the paper's half-warp phantom generalised to 128-lane tiles), the
+interpret-or-compile decision (:func:`pallas_interpret`), and unpadding of
+results.
 """
 
 from __future__ import annotations
@@ -24,7 +25,11 @@ from repro.kernels.tree_eval.quant import QuantizedForest, packed_forest_nbytes
 
 LANE = 128          # TPU vector lane count / MXU edge
 SUBLANE = 8
-VMEM_BUDGET = 8 * 2**20  # conservative half of a v5e core's ~16 MiB VMEM
+# Half of the 16 MiB scoped-VMEM limit Mosaic applies on a v5e core by
+# default; the other half is headroom for temporaries the model below does
+# not count.  tests/test_chip_compile.py compiles the chosen tiles for v5e.
+VMEM_BUDGET = 8 * 2**20
+MAX_BLOCK_M = 1024
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -35,24 +40,61 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def choose_block_m(n_nodes: int, n_attrs: int, *, jump_mode: str = "gather") -> int:
-    """Pick the record-tile height from a VMEM footprint model.
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernels run in the interpreter on this backend.
 
-    Per-tile VMEM ≈ records (BM·A·4) + path copies (≈3·BM·N·4) + tables
-    (A·N·4 + 3·N·4); the onehot jump additionally materialises a
-    (BM, N, N) one-hot → dominate by BM·N²·4.  We take the largest power-of-
-    two BM ≤ 1024 that fits the budget (≥ SUBLANE).
+    The one place the choice is made: interpreted on the CPU backend (the
+    test suite), compiled on TPU, and refused anywhere else — there is no
+    silent fallback from a device to the interpreter.
     """
-    tables = n_attrs * n_nodes * 4 + 3 * n_nodes * 4
-    bm = 1024
-    while bm > SUBLANE:
-        per_tile = bm * n_attrs * 4 + 3 * bm * n_nodes * 4
-        if jump_mode == "onehot":
-            per_tile += bm * n_nodes * n_nodes * 4
-        if tables + per_tile <= VMEM_BUDGET:
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"the tree-eval Pallas kernels compile for TPU and are interpreted on "
+        f"CPU; backend {backend!r} has neither path")
+
+
+def vmem_bytes(block_m: int, n_nodes: int, n_attrs: int, *, jump_mode: str = "gather") -> int:
+    """Modelled VMEM of one grid step (padded N and A, f32/int32 words).
+
+    Blocks are double-buffered by the Pallas pipeline: the record tile
+    (BM·A), one tree's ``attr_select`` (A·N), its scalar tables (each a
+    (1, N) row padded to a sublane tile) and the output tile (BM rows of
+    one 128-lane vreg).  Temporaries: about four (BM, N) arrays (node
+    values, the path and the jump's partial gathers); the onehot jump adds
+    its (BM, N, N) one-hot.
+    """
+    blocks = block_m * n_attrs + n_attrs * n_nodes + 4 * SUBLANE * n_nodes + block_m * LANE
+    temps = 4 * block_m * n_nodes
+    if jump_mode == "onehot":
+        temps += block_m * n_nodes * n_nodes
+    return 4 * (2 * blocks + temps)
+
+
+def choose_block_m(n_nodes: int, n_attrs: int, *, jump_mode: str = "gather") -> int:
+    """The largest power-of-two record tile (≤ MAX_BLOCK_M) whose modelled
+    VMEM (:func:`vmem_bytes`) fits ``VMEM_BUDGET``.
+
+    Raises:
+      ValueError: when not even a one-sublane tile fits (e.g. the onehot
+        jump at N = 512, whose one-hot alone is 8 MiB per 8 records).
+    """
+    bm = MAX_BLOCK_M
+    while bm >= SUBLANE:
+        if vmem_bytes(bm, n_nodes, n_attrs, jump_mode=jump_mode) <= VMEM_BUDGET:
             return bm
         bm //= 2
-    return SUBLANE
+    raise ValueError(
+        f"no record tile fits {VMEM_BUDGET} B of VMEM at N={n_nodes}, "
+        f"A={n_attrs}, jump_mode={jump_mode!r}")
+
+
+def block_m_fits(n_nodes: int, n_attrs: int, *, jump_mode: str = "gather") -> bool:
+    """Whether any record tile fits (the kernel can run at this width)."""
+    return vmem_bytes(SUBLANE, n_nodes, n_attrs, jump_mode=jump_mode) <= VMEM_BUDGET
 
 
 class PackedTree:
@@ -103,32 +145,14 @@ def _tree_eval_padded(
     max_depth: int,
     interpret: bool,
 ):
-    if algorithm == "speculative":
-        out = _k.speculative_pallas(
-            records,
-            attr_select,
-            threshold,
-            child,
-            class_val,
-            total_jumps=jumps,
-            block_m=block_m,
-            jump_mode=jump_mode,
-            interpret=interpret,
-        )
-    elif algorithm == "data_parallel":
-        out = _k.data_parallel_pallas(
-            records,
-            attr_idx,
-            threshold,
-            child,
-            class_val,
-            max_depth=max_depth,
-            block_m=block_m,
-            interpret=interpret,
-        )
-    else:
-        raise ValueError(f"unknown algorithm {algorithm!r}")
-    return out[:, 0]
+    """One tree ((1, N) tables, (A, N) ``attr_select``) as a T = 1 forest."""
+    first = attr_select[None] if algorithm == "speculative" else attr_idx
+    out = _k.forest_pallas(
+        records, (first, threshold, child, class_val),
+        algorithm=algorithm, block_m=block_m, interpret=interpret,
+        total_jumps=jumps, jump_mode=jump_mode, max_depth=max_depth,
+    )
+    return out[0, :, 0]
 
 
 def tree_eval(
@@ -139,7 +163,6 @@ def tree_eval(
     algorithm: str = "speculative",
     jump_mode: str = "gather",
     block_m: int | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Evaluate a classification tree over a record batch with a TPU kernel.
 
@@ -150,17 +173,14 @@ def tree_eval(
       algorithm: "speculative" (Procedure 4/5) or "data_parallel" (Procedure 3).
       jump_mode: "gather" | "onehot" pointer-jump implementation.
       block_m: records per tile; default = VMEM-model choice.
-      interpret: force Pallas interpret mode; default = auto (True off-TPU).
 
     Returns:
       (M,) int32 class assignments.
     """
     if isinstance(tree, EncodedTree):
         if n_attrs is None:
-            n_attrs = int(np.asarray(records).shape[-1])
+            n_attrs = int(np.shape(records)[-1])
         tree = PackedTree(tree, n_attrs)
-    if interpret is None:
-        interpret = not on_tpu()
     if block_m is None:
         block_m = choose_block_m(tree.n_nodes, tree.n_attrs_padded, jump_mode=jump_mode)
     records = jnp.asarray(records)
@@ -182,7 +202,7 @@ def tree_eval(
         jump_mode=jump_mode,
         jumps=jumps,
         max_depth=tree.max_depth,
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )
     return out[:m]
 
@@ -258,31 +278,12 @@ def _forest_eval_padded(
     max_depth: int,
     interpret: bool,
 ):
-    if algorithm == "speculative":
-        out = _k.fused_speculative_pallas(
-            records,
-            attr_select,
-            threshold,
-            child,
-            class_val,
-            total_jumps=jumps,
-            block_m=block_m,
-            jump_mode=jump_mode,
-            interpret=interpret,
-        )
-    elif algorithm == "data_parallel":
-        out = _k.fused_data_parallel_pallas(
-            records,
-            attr_idx,
-            threshold,
-            child,
-            class_val,
-            max_depth=max_depth,
-            block_m=block_m,
-            interpret=interpret,
-        )
-    else:
-        raise ValueError(f"unknown algorithm {algorithm!r}")
+    first = attr_select if algorithm == "speculative" else attr_idx
+    out = _k.forest_pallas(
+        records, (first, threshold, child, class_val),
+        algorithm=algorithm, block_m=block_m, interpret=interpret,
+        total_jumps=jumps, jump_mode=jump_mode, max_depth=max_depth,
+    )
     return out[:, :, 0]
 
 
@@ -294,7 +295,6 @@ def forest_eval_fused(
     algorithm: str = "speculative",
     jump_mode: str = "gather",
     block_m: int | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Evaluate a whole forest with one fused Pallas launch.
 
@@ -305,7 +305,6 @@ def forest_eval_fused(
       algorithm: "speculative" (Procedure 4/5) or "data_parallel" (Procedure 3).
       jump_mode: "gather" | "onehot" pointer-jump implementation.
       block_m: records per tile; default = VMEM-model choice.
-      interpret: force Pallas interpret mode; default = auto (True off-TPU).
 
     Returns:
       (T, M) int32 per-tree class assignments, bit-identical to running
@@ -313,10 +312,8 @@ def forest_eval_fused(
     """
     if not isinstance(forest, PackedForest):
         if n_attrs is None:
-            n_attrs = int(np.asarray(records).shape[-1])
+            n_attrs = int(np.shape(records)[-1])
         forest = PackedForest(forest, n_attrs)
-    if interpret is None:
-        interpret = not on_tpu()
     if block_m is None:
         block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode=jump_mode)
     records = jnp.asarray(records)
@@ -338,7 +335,7 @@ def forest_eval_fused(
         jump_mode=jump_mode,
         jumps=jumps,
         max_depth=forest.max_depth,
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )
     return out[:, :m]
 
@@ -360,18 +357,11 @@ def _quant_forest_eval_padded(
     max_depth: int,
     interpret: bool,
 ):
-    if algorithm == "speculative":
-        out = _k.fused_speculative_q_pallas(
-            records, attr_idx, threshold, child, class_val,
-            total_jumps=jumps, block_m=block_m, interpret=interpret,
-        )
-    elif algorithm == "data_parallel":
-        out = _k.fused_data_parallel_q_pallas(
-            records, attr_idx, threshold, child, class_val,
-            max_depth=max_depth, block_m=block_m, interpret=interpret,
-        )
-    else:
-        raise ValueError(f"unknown algorithm {algorithm!r}")
+    out = _k.forest_pallas(
+        records, (attr_idx, threshold, child, class_val),
+        algorithm=algorithm, layout="quant", block_m=block_m, interpret=interpret,
+        total_jumps=jumps, max_depth=max_depth,
+    )
     return out[:, :, 0]
 
 
@@ -384,14 +374,14 @@ def forest_eval_fused_q(
     thr_dtype: str = "bfloat16",
     calibration=None,
     block_m: int | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Evaluate a whole forest with one fused launch over *quantized* tables.
 
     The compact-layout dual of :func:`forest_eval_fused`: node tables arrive
     as int8/int16 indices and bf16/f16 split-safe thresholds (see
-    :mod:`repro.kernels.tree_eval.quant`) and node evaluation gathers each
-    record's attribute directly instead of multiplying by ``attr_select``.
+    :mod:`repro.kernels.tree_eval.quant`) and no ``attr_select`` is stored:
+    the speculative kernel builds the one-hot selection in VMEM from the
+    index table, the data-parallel kernel reads each record's attribute.
 
     Args:
       records: (M, A) float array (compared in f32 after upcast).
@@ -401,22 +391,22 @@ def forest_eval_fused_q(
         cast round-trips exactly, so results are bit-exact for *any* input).
       algorithm: "speculative" (Procedure 4/5) or "data_parallel" (Procedure 3).
       block_m: records per tile; default = VMEM-model choice.
-      interpret: force Pallas interpret mode; default = auto (True off-TPU).
 
     Returns:
       (T, M) int32 per-tree class assignments.
     """
     if not isinstance(forest, QuantizedForest):
         if n_attrs is None:
-            n_attrs = int(np.asarray(records).shape[-1])
+            n_attrs = int(np.shape(records)[-1])
         forest = QuantizedForest(
             forest, n_attrs, thr_dtype=thr_dtype, calibration=calibration
         )
-    if interpret is None:
-        interpret = not on_tpu()
     if block_m is None:
         block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode="gather")
     records = jnp.asarray(records)
+    if algorithm == "speculative":
+        # the in-kernel one-hot product has the records@S contract (inf*0 = NaN)
+        records = sanitize_records(records)
     padded, m = _pad_records(records, block_m, forest.n_attrs_padded)
     jumps = max(1, math.ceil(math.log2(max(forest.max_depth, 2))))
     out = _quant_forest_eval_padded(
@@ -429,7 +419,7 @@ def forest_eval_fused_q(
         block_m=block_m,
         jumps=jumps,
         max_depth=forest.max_depth,
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )
     return out[:, :m]
 
@@ -456,32 +446,12 @@ def _forest_votes_padded(
     c_pad: int,
     interpret: bool,
 ):
-    if algorithm == "speculative":
-        return _k.fused_votes_speculative_pallas(
-            records,
-            attr_select,
-            threshold,
-            child,
-            class_val,
-            n_classes=c_pad,
-            total_jumps=jumps,
-            block_m=block_m,
-            jump_mode=jump_mode,
-            interpret=interpret,
-        )
-    if algorithm == "data_parallel":
-        return _k.fused_votes_data_parallel_pallas(
-            records,
-            attr_idx,
-            threshold,
-            child,
-            class_val,
-            n_classes=c_pad,
-            max_depth=max_depth,
-            block_m=block_m,
-            interpret=interpret,
-        )
-    raise ValueError(f"unknown algorithm {algorithm!r}")
+    first = attr_select if algorithm == "speculative" else attr_idx
+    return _k.forest_pallas(
+        records, (first, threshold, child, class_val),
+        algorithm=algorithm, block_m=block_m, interpret=interpret, n_classes=c_pad,
+        total_jumps=jumps, jump_mode=jump_mode, max_depth=max_depth,
+    )
 
 
 def forest_votes_fused(
@@ -493,7 +463,6 @@ def forest_votes_fused(
     algorithm: str = "speculative",
     jump_mode: str = "gather",
     block_m: int | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Accumulate the forest's class votes in one fused Pallas launch.
 
@@ -508,10 +477,8 @@ def forest_votes_fused(
     """
     if not isinstance(forest, PackedForest):
         if n_attrs is None:
-            n_attrs = int(np.asarray(records).shape[-1])
+            n_attrs = int(np.shape(records)[-1])
         forest = PackedForest(forest, n_attrs)
-    if interpret is None:
-        interpret = not on_tpu()
     if block_m is None:
         block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode=jump_mode)
     c_pad = _round_up(max(int(n_classes), 2), LANE)
@@ -534,7 +501,7 @@ def forest_votes_fused(
         jumps=jumps,
         max_depth=forest.max_depth,
         c_pad=c_pad,
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )
     return out[:m, :n_classes]
 

@@ -1,7 +1,8 @@
 """Roofline-term derivation from a compiled dry-run cell.
 
-TPU v5e constants (per chip): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI.  Terms in seconds:
+Device peaks live in one table, :data:`DEVICE_PEAKS`, keyed by JAX's
+``device_kind``.  The dry-run cells target TPU v5e (per chip: 197 TFLOP/s
+bf16, 819 GB/s HBM, ~50 GB/s/link ICI).  Terms in seconds:
 
     compute    = HLO_FLOPs / (chips × 197e12)
     memory     = HLO_bytes / (chips × 819e9)
@@ -21,9 +22,43 @@ import dataclasses
 import json
 from typing import Any, Optional
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link (effective)
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one device kind."""
+
+    bf16_flops: float      # FLOP/s
+    int8_ops: float        # OP/s
+    hbm_bw: float          # bytes/s
+    hbm_bytes: float       # capacity
+    ici_bw: float          # bytes/s per link, effective
+
+
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s.  ICI is the
+# effective per-link figure the dry-run collective term has always used.
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bw=819e9, hbm_bytes=16e9, ici_bw=50e9),
+}
+
+
+def device_peaks(device_kind: str | None = None) -> DevicePeaks | None:
+    """Peaks for ``device_kind`` (default: the first JAX device's), or None
+    for a device the table does not hold — no other device's numbers stand
+    in for it."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind)
+
+
+# The dry-run cells' target chip (launch/dryrun.py compiles for v5e).
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E.bf16_flops
+HBM_BW = _V5E.hbm_bw
+ICI_BW = _V5E.ici_bw
 
 
 @dataclasses.dataclass

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.base import ParallelConfig, ShapeConfig, TrainConfig
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.data.pipeline import pipeline_for
+from repro.launch.mesh import auto_mesh
 from repro.models.api import build_model
 from repro.optim.adamw import adamw_init
 from repro.parallel import sharding as shd
@@ -45,10 +46,10 @@ def main(argv=None):
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.mesh == "auto":
         n = jax.device_count()
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        mesh = auto_mesh((n, 1), ("data", "model"))
     else:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = auto_mesh((d, m), ("data", "model"))
     axes = shd.from_mesh(mesh)
     model = build_model(cfg, axes, ParallelConfig())
     print(f"arch={cfg.name} params={cfg.n_params()/1e6:.1f}M "

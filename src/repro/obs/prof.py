@@ -40,6 +40,7 @@ thresholds, noisy ones are not flagged for breathing.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import statistics
 import threading
 import time
@@ -50,6 +51,8 @@ import numpy as np
 
 from repro.obs.metrics import DEFAULT_RATIO_BOUNDARIES, Registry
 from repro.obs.trace import NULL_TRACER, Tracer
+
+log = logging.getLogger(__name__)
 
 __all__ = [
     "BucketProfile",
@@ -327,7 +330,10 @@ class TraversalProfiler:
                 prof = self.profile_fn(snap)
             self._publish(key, snap, prof)
         except Exception:
+            # the shadow pass must not take serving down, but it must not
+            # fail unseen either: counted (prof.errors) and logged
             self.m_errors.inc()
+            log.exception("shadow profile of bucket %s failed", key)
 
     def _publish(self, key: str, snap: np.ndarray, prof) -> None:
         exit_depth = np.asarray(prof.exit_depth).ravel()

@@ -20,14 +20,9 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 top-level API
-    from jax import shard_map
+from jax import shard_map  # noqa: F401 (re-exported)
 
-    SHARD_MAP_KW = {"check_vma": False}
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401 (re-exported)
-
-    SHARD_MAP_KW = {"check_rep": False}
+SHARD_MAP_KW = {"check_vma": False}
 
 
 @dataclasses.dataclass(frozen=True)

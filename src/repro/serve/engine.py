@@ -18,6 +18,7 @@ share one length (shorter prompts are left-padded by the caller or the
 from __future__ import annotations
 
 import dataclasses
+import logging
 import threading
 import time
 from collections import deque
@@ -28,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+
+log = logging.getLogger(__name__)
 
 # Histogram grid for anytime stage counts (cascades rarely exceed 8 stages).
 _STAGE_BOUNDARIES = tuple(float(i) for i in range(1, 9))
@@ -306,6 +309,7 @@ class BackgroundRetuner:
                 self.done.append((key, entry))
         except Exception as e:  # a failed re-tune must never take serving down
             self.m_failed.inc()
+            log.exception("background re-tune of bucket %s failed", key)
             with self._lock:
                 self.errors.append((key, e))
 
@@ -766,10 +770,14 @@ class ForestServeEngine:
         """The wave cascade, built once and calibrated on the first wave."""
         if self._cascade is None:
             from repro.kernels.tree_eval import CascadeEvaluator
+            from repro.tune.space import default_engines
 
             pol = self.anytime
+            # the engine restriction covers the cascade's stage kernels too
+            engines = self._eval.engines or default_engines()
             self._cascade = CascadeEvaluator(
                 self.forest,
+                engine="pallas" if "pallas" in engines else "jnp",
                 n_classes=self.n_classes,
                 bound=pol.bound,
                 stages=pol.stages,

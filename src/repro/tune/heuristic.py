@@ -19,7 +19,7 @@ from __future__ import annotations
 
 from repro.core.analysis import CostModel, t3_data_parallel, t5_speculative
 from repro.kernels.tree_eval.cascade import MAJORITY_FAMILY, plan_cascade
-from repro.kernels.tree_eval.ops import PER_TREE_FAMILY, choose_block_m, on_tpu
+from repro.kernels.tree_eval.ops import PER_TREE_FAMILY, block_m_fits, choose_block_m, on_tpu
 from repro.tune.space import (
     MAX_ONEHOT_NODES,
     Candidate,
@@ -34,6 +34,18 @@ from repro.tune.space import (
 # families pay once.  Only the *ratio* against the compute terms matters —
 # the heuristic ranks families, it does not predict milliseconds.
 FOREST_LAUNCH_OVERHEAD = 50.0
+
+
+def _pallas_jump_mode(shape: WorkloadShape) -> str:
+    """The Pallas pointer-jump flavour: the all-MXU onehot jump on TPU
+    where its (BM, N, N) one-hot fits VMEM, lane gathers otherwise."""
+    b = shape.bucket()
+    onehot_ok = (
+        on_tpu()
+        and shape.n_nodes <= MAX_ONEHOT_NODES
+        and block_m_fits(b.n_nodes, b.n_attrs, jump_mode="onehot")
+    )
+    return "onehot" if onehot_ok else "gather"
 
 
 def default_p_group(shape: WorkloadShape) -> int:
@@ -123,12 +135,11 @@ def heuristic_candidate(
     engines = default_engines() if engines is None else tuple(engines)
     engine = "pallas" if "pallas" in engines else "jnp"
 
-    onehot_ok = shape.n_nodes <= MAX_ONEHOT_NODES
     if engine == "pallas":
         if algorithm == "data_parallel":
             name, jump_mode = "pallas_data_parallel", "gather"
         else:
-            jump_mode = "onehot" if (on_tpu() and onehot_ok) else "gather"
+            jump_mode = _pallas_jump_mode(shape)
             name = f"pallas_speculative_{jump_mode}"
         b = shape.bucket()
         bm = choose_block_m(b.n_nodes, b.n_attrs, jump_mode=jump_mode)
@@ -245,11 +256,10 @@ def forest_heuristic_candidate(
 
     times = predicted_times(deep, cm=cm, d_mu=d_deep, p_group=p_group)
     algorithm = min(times, key=times.get)
-    onehot_ok = shape.n_nodes <= MAX_ONEHOT_NODES
     if algorithm == "data_parallel":
         name, jump_mode = f"forest_{family}_data_parallel", "gather"
     else:
-        jump_mode = "onehot" if (engine == "pallas" and on_tpu() and onehot_ok) else "gather"
+        jump_mode = _pallas_jump_mode(deep) if engine == "pallas" else "gather"
         name = f"forest_{family}_speculative_{jump_mode}"
 
     if family == "fused":
@@ -391,16 +401,17 @@ def cascade_heuristic_candidate(
     engine = "pallas" if "pallas" in engines else "jnp"
     times = predicted_times(deep, cm=cm, d_mu=d_mu, p_group=p_group)
     algorithm = min(times, key=times.get)
-    onehot_ok = shape.n_nodes <= MAX_ONEHOT_NODES
     family = "fused" if engine == "pallas" else "vmap"
+    jump_mode = "gather"
     if algorithm == "data_parallel":
         name = f"forest_cascade_{family}_data_parallel"
     else:
-        jump_mode = "onehot" if (engine == "pallas" and on_tpu() and onehot_ok) else "gather"
+        if engine == "pallas":
+            jump_mode = _pallas_jump_mode(deep)
         name = f"forest_cascade_{family}_speculative_{jump_mode}"
     if engine == "pallas":
         b = shape.bucket()
-        bm = choose_block_m(b.n_nodes, b.n_attrs, jump_mode="gather")
+        bm = choose_block_m(b.n_nodes, b.n_attrs, jump_mode=jump_mode)
         return Candidate.make(name, stages=stages, block_m=bm)
     return Candidate.make(name, stages=stages)
 

@@ -10,6 +10,7 @@ iterations so one-off scheduling noise doesn't crown the wrong variant.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 
 import jax
@@ -35,6 +36,8 @@ from repro.tune.space import (
     search_space,
 )
 
+log = logging.getLogger(__name__)
+
 
 @dataclasses.dataclass(frozen=True)
 class Measurement:
@@ -51,6 +54,8 @@ class Measurement:
     # target (per-tree family).  Sits next to the HLO-cost gauges so layout
     # sweeps can weigh latency against footprint.
     table_bytes: float | None = None
+    # What the candidate raised, for a failed measurement (see _failed).
+    error: str | None = None
 
     @property
     def failed(self) -> bool:
@@ -73,21 +78,25 @@ def _median(xs) -> float:
     return xs[mid] if n % 2 else (xs[mid - 1] + xs[mid]) / 2.0
 
 
-def roofline_fraction(flops: float, bytes_: float, median_ms: float) -> float:
+def roofline_fraction(flops: float, bytes_: float, median_ms: float,
+                      device_kind: str | None = None) -> float | None:
     """Achieved fraction of the hardware bound for one measured candidate.
 
-    ``max(flops/PEAK_FLOPS, bytes/HBM_BW)`` is the shortest time the chip
+    ``max(flops/peak_flops, bytes/hbm_bw)`` is the shortest time the chip
     could possibly take (the roofline floor); dividing by the measured time
-    says how close the candidate got.  Peaks are the TPU v5e constants from
-    :mod:`repro.launch.roofline` — on the CPU interpret path the fraction is
-    honest but tiny (the point is the *trend* across candidates and PRs, not
-    the absolute value off-TPU).
+    says how close the candidate got.  Peaks come from
+    :data:`repro.launch.roofline.DEVICE_PEAKS` for ``device_kind`` (default:
+    the first JAX device's).  A device that is not in the table — the CPU
+    among them — has no roofline, and the result is None.
     """
-    from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+    from repro.launch.roofline import device_peaks
 
+    peaks = device_peaks(device_kind)
+    if peaks is None:
+        return None
     if median_ms <= 0 or median_ms == float("inf"):
         return 0.0
-    floor_s = max(flops / PEAK_FLOPS, bytes_ / HBM_BW)
+    floor_s = max(flops / peaks.bf16_flops, bytes_ / peaks.hbm_bw)
     return floor_s / (median_ms / 1e3)
 
 
@@ -110,8 +119,9 @@ def candidate_cost(fn, records, *, median_ms: float | None = None) -> dict | Non
     except Exception:
         return None
     out = {"flops": float(cost.flops), "bytes": float(cost.bytes)}
-    if median_ms is not None:
-        out["roofline_frac"] = roofline_fraction(cost.flops, cost.bytes, median_ms)
+    frac = None if median_ms is None else roofline_fraction(cost.flops, cost.bytes, median_ms)
+    if frac is not None:
+        out["roofline_frac"] = frac
     return out
 
 
@@ -139,7 +149,8 @@ def _note_measurements(registry, level: str, measurements) -> None:
         ("level", "variant"))
     g_roof = r.gauge(
         "tune.roofline_frac",
-        "achieved fraction of the hardware roofline bound (see launch/roofline.py)",
+        "achieved fraction of the device's roofline bound "
+        "(launch/roofline.py DEVICE_PEAKS; absent off the table)",
         ("level", "variant"))
     g_tbytes = r.gauge(
         "tune.candidate_table_bytes",
@@ -155,7 +166,8 @@ def _note_measurements(registry, level: str, measurements) -> None:
             v = m.candidate.variant
             g_flops.labels(level=level, variant=v).set(m.cost["flops"])
             g_bytes.labels(level=level, variant=v).set(m.cost["bytes"])
-            g_roof.labels(level=level, variant=v).set(m.cost.get("roofline_frac", 0.0))
+            if "roofline_frac" in m.cost:
+                g_roof.labels(level=level, variant=v).set(m.cost["roofline_frac"])
         if m.table_bytes is not None:
             g_tbytes.labels(level=level, variant=m.candidate.variant).set(m.table_bytes)
 
@@ -242,6 +254,17 @@ def bucket_pad_records(records: jax.Array, bucket_m: int) -> jax.Array:
     return jnp.zeros((bucket_m, records.shape[1]), records.dtype).at[:m].set(records)
 
 
+def _failed(level: str, candidate: Candidate, exc: Exception) -> Measurement:
+    """A candidate that raised: logged with its error and returned as a
+    failed measurement (counted as ``tune.failed_candidates`` by the sweep).
+    Valid candidates do not raise — the search space offers only what the
+    device can run — so this is a bug to read, not a quiet loss."""
+    err = f"{type(exc).__name__}: {exc}"
+    log.warning("tune %s candidate %s %s raised %s", level, candidate.variant,
+                candidate.param_dict, err)
+    return Measurement(candidate, float("inf"), (), error=err)
+
+
 def measure_candidate(
     candidate: Candidate,
     records,
@@ -251,7 +274,8 @@ def measure_candidate(
     warmup: int = 2,
     iters: int = 5,
 ) -> Measurement:
-    """Median wall time of one candidate; a raising candidate measures as ∞.
+    """Median wall time of one candidate; a raising candidate is logged and
+    returned as a failed measurement.
 
     Args:
       candidate: the (variant, params) pair to time.
@@ -261,9 +285,8 @@ def measure_candidate(
       warmup/iters: :func:`time_callable` discipline.
 
     Returns:
-      A :class:`Measurement`; ``failed`` (empty samples, median ∞) when
-      the candidate raised — invalid candidates lose, they don't crash the
-      sweep.
+      A :class:`Measurement`; ``failed`` (empty samples, median ∞, the
+      ``error`` text) when the candidate raised.
     """
     spec = get_variant(candidate.variant)
     params = candidate.param_dict
@@ -273,8 +296,8 @@ def measure_candidate(
 
     try:
         samples = time_callable(lambda: fn(records), warmup=warmup, iters=iters)
-    except Exception:
-        return Measurement(candidate, float("inf"), ())
+    except Exception as exc:
+        return _failed("tree", candidate, exc)
     median = _median(samples)
     return Measurement(candidate, median, samples,
                        candidate_cost(fn, records, median_ms=median))
@@ -391,7 +414,8 @@ def measure_forest_candidate(
     iters: int = 5,
     autotune_trees: bool = False,
 ) -> Measurement:
-    """Median wall time of one forest candidate; a raising candidate is ∞.
+    """Median wall time of one forest candidate; a raising candidate is
+    logged and returned as a failed measurement.
 
     Args:
       candidate: a :func:`repro.tune.space.forest_search_space` candidate
@@ -415,8 +439,8 @@ def measure_forest_candidate(
             measure_kw={"warmup": warmup, "iters": iters},
         )
         samples = time_callable(lambda: fn(records), warmup=warmup, iters=iters)
-    except Exception:
-        return Measurement(candidate, float("inf"), ())
+    except Exception as exc:
+        return _failed("forest", candidate, exc)
     median = _median(samples)
     return Measurement(candidate, median, samples,
                        candidate_cost(fn, records, median_ms=median),
@@ -550,8 +574,8 @@ def measure_cascade_candidate(
             rec_np = np.asarray(records, np.float32)
             run = lambda: ev(rec_np).classes  # noqa: E731
         samples = time_callable(run, warmup=warmup, iters=iters)
-    except Exception:
-        return Measurement(candidate, float("inf"), ())
+    except Exception as exc:
+        return _failed("classes", candidate, exc)
     return Measurement(candidate, _median(samples), samples)
 
 

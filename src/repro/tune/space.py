@@ -4,9 +4,9 @@ A *workload shape* is the 4-tuple the paper's §4 runtime model is written
 over: record count M, node count N, attribute count A and tree depth d.
 Candidates are (variant, params) pairs drawn from the kernel variant
 registry (:mod:`repro.kernels.tree_eval.ops`); :func:`search_space`
-enumerates only the candidates that are *valid* for a given shape (e.g. the
-one-hot MXU formulation is excluded when the N² one-hot would blow the
-VMEM/FLOP budget).
+enumerates only the candidates that are *valid* for a given shape: a Pallas
+kernel whose record tile cannot fit VMEM (the onehot jump at N = 512) is
+one the chip's compiler refuses, so it is never offered.
 
 Shapes are *bucketed* before they key the cache: M rounds up to a power of
 two, N and A round up to the 128-lane tile the kernels pad to anyway, and
@@ -41,6 +41,7 @@ from repro.kernels.tree_eval.ops import (
     ForestVariantSpec,
     VariantSpec,
     _round_up,
+    block_m_fits,
     choose_block_m,
     list_forest_variants,
     list_variants,
@@ -139,11 +140,11 @@ class Candidate:
 
 
 def _block_m_grid(shape: WorkloadShape, jump_mode: str) -> list[int]:
-    """VMEM-model block size plus its power-of-two neighbours."""
+    """The VMEM model's block size and the next smaller power of two (the
+    larger neighbour does not fit the model by construction)."""
     b = shape.bucket()
     base = choose_block_m(b.n_nodes, b.n_attrs, jump_mode=jump_mode)
-    grid = {base, max(base // 2, SUBLANE), min(base * 2, 1024)}
-    return sorted(x for x in grid if SUBLANE <= x <= 1024)
+    return sorted({base, max(base // 2, SUBLANE)})
 
 
 def _jumps_grid(shape: WorkloadShape) -> list[int]:
@@ -164,11 +165,18 @@ def default_engines() -> tuple[str, ...]:
     return ("pallas", "jnp") if on_tpu() else ("jnp",)
 
 
-def variant_valid(spec: VariantSpec, shape: WorkloadShape) -> bool:
-    """Whether ``spec`` is worth timing at ``shape`` (see MAX_ONEHOT_NODES)."""
+def variant_valid(spec: VariantSpec | ForestVariantSpec, shape: WorkloadShape) -> bool:
+    """Whether ``spec`` can run at ``shape`` and is worth timing there.
+
+    Onehot formulations stop past MAX_ONEHOT_NODES; a Pallas kernel also
+    needs a record tile that fits VMEM (:func:`ops.block_m_fits`) — without
+    one the chip's compiler refuses it.
+    """
     if spec.jump_mode == "onehot" and shape.n_nodes > MAX_ONEHOT_NODES:
         return False
-    return True
+    b = shape.bucket()
+    return spec.engine != "pallas" or block_m_fits(
+        b.n_nodes, b.n_attrs, jump_mode=spec.jump_mode)
 
 
 def search_space(
@@ -297,9 +305,7 @@ class ForestShape:
 
 
 def forest_variant_valid(spec: ForestVariantSpec, shape: ForestShape) -> bool:
-    if spec.jump_mode == "onehot" and shape.n_nodes > MAX_ONEHOT_NODES:
-        return False
-    return True
+    return variant_valid(spec, shape.tree_shape())
 
 
 def forest_search_space(
@@ -406,9 +412,7 @@ def cascade_search_space(
         return
     tshape = shape.tree_shape()
     for spec in list_cascade_variants():
-        if spec.engine not in engines:
-            continue
-        if spec.jump_mode == "onehot" and shape.n_nodes > MAX_ONEHOT_NODES:
+        if spec.engine not in engines or not variant_valid(spec, tshape):
             continue
         for s in stage_grid:
             if "block_m" in spec.tunables:

@@ -144,7 +144,7 @@ def test_cascade_engines_agree_with_reference():
     )
     for kw in (
         dict(engine="jnp"),
-        dict(engine="pallas", block_m=64, interpret=True),
+        dict(engine="pallas", block_m=64),
         dict(engine="jnp", algorithm="data_parallel"),
     ):
         ev = CascadeEvaluator(forest, plan, n_classes=5, bound=1.0, **kw)
@@ -169,7 +169,7 @@ def test_forest_votes_fused_matches_onehot_sum():
     ):
         votes = np.asarray(forest_votes_fused(
             rec, forest, n_classes=4, algorithm=algorithm, jump_mode=jump_mode,
-            block_m=64, interpret=True,
+            block_m=64,
         ))
         assert votes.shape == (rec.shape[0], 4)
         assert np.array_equal(votes, want), (algorithm, jump_mode)

@@ -54,7 +54,8 @@ def test_sharded_train_step_matches_single_device():
         s1 = jax.jit(make_train_step(m1, tcfg))
         _, _, met1 = s1(p1, adamw_init(p1), batch)
 
-        mesh = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh = jax.make_mesh((2, 2), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         axes = shd.from_mesh(mesh)
         m2 = build_model(cfg, axes)
         with mesh:

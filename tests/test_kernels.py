@@ -170,3 +170,46 @@ def test_explicit_block_m_override():
     for bm in (8, 16, 64):
         out = np.asarray(tree_eval(rec, enc, algorithm="speculative", block_m=bm))
         assert np.array_equal(out, _ref(enc, rec))
+
+
+def test_block_m_refuses_when_no_tile_fits():
+    """No fallback tile: where nothing fits the budget, choose_block_m raises."""
+    from repro.kernels.tree_eval.ops import VMEM_BUDGET, block_m_fits, vmem_bytes
+
+    assert not block_m_fits(512, 128, jump_mode="onehot")
+    with pytest.raises(ValueError, match="no record tile fits"):
+        choose_block_m(512, 128, jump_mode="onehot")
+    for n in (128, 512, 1024):
+        for jm in ("gather", "onehot"):
+            if block_m_fits(n, 128, jump_mode=jm):
+                bm = choose_block_m(n, 128, jump_mode=jm)
+                assert vmem_bytes(bm, n, 128, jump_mode=jm) <= VMEM_BUDGET
+
+
+def test_pallas_interpret_follows_the_backend(monkeypatch):
+    """Interpreted on CPU, compiled on TPU, refused elsewhere."""
+    import jax
+
+    from repro.kernels.tree_eval.ops import pallas_interpret
+
+    assert pallas_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="backend 'gpu'"):
+        pallas_interpret()
+
+
+def test_search_space_offers_only_fitting_pallas_tiles():
+    """The onehot Pallas jump is not offered where its one-hot cannot fit."""
+    from repro.tune.space import WorkloadShape, search_space
+
+    wide = WorkloadShape(m=1024, n_nodes=511, n_attrs=19, depth=8)
+    names = {c.variant for c in search_space(wide, engines=("pallas",))}
+    assert names == {"pallas_speculative_gather", "pallas_data_parallel"}
+    narrow = WorkloadShape(m=1024, n_nodes=100, n_attrs=19, depth=7)
+    cands = list(search_space(narrow, engines=("pallas",)))
+    assert any(c.variant == "pallas_speculative_onehot" for c in cands)
+    for c in cands:
+        jm = "onehot" if c.variant.endswith("onehot") else "gather"
+        assert c.param_dict["block_m"] <= choose_block_m(128, 128, jump_mode=jm)

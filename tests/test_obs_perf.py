@@ -298,15 +298,20 @@ class TestCheckRegressionsCLI:
 
 class TestCandidateCost:
     def test_roofline_fraction_math(self):
-        from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+        from repro.launch.roofline import DEVICE_PEAKS
         from repro.tune.measure import roofline_fraction
 
+        kind = "TPU v5 lite"
+        peaks = DEVICE_PEAKS[kind]
         # memory-bound: floor = bytes/BW; 1 s of HBM traffic in 2 s -> 0.5
-        assert roofline_fraction(0.0, HBM_BW, 2000.0) == pytest.approx(0.5)
+        assert roofline_fraction(0.0, peaks.hbm_bw, 2000.0, kind) == pytest.approx(0.5)
         # compute-bound: floor = flops/peak
-        assert roofline_fraction(PEAK_FLOPS, 0.0, 1000.0) == pytest.approx(1.0)
-        assert roofline_fraction(1.0, 1.0, 0.0) == 0.0
-        assert roofline_fraction(1.0, 1.0, float("inf")) == 0.0
+        assert roofline_fraction(peaks.bf16_flops, 0.0, 1000.0, kind) == pytest.approx(1.0)
+        assert roofline_fraction(1.0, 1.0, 0.0, kind) == 0.0
+        assert roofline_fraction(1.0, 1.0, float("inf"), kind) == 0.0
+        # a device outside the table has no roofline — never v5e numbers
+        assert roofline_fraction(1.0, 1.0, 1.0, "cpu") is None
+        assert roofline_fraction(1.0, 1.0, 1.0) is None   # this host's CPU
 
     def test_measure_candidate_carries_cost(self):
         import jax.numpy as jnp
@@ -327,7 +332,8 @@ class TestCandidateCost:
         # dot/conv FLOPs are ~0 — assert the memory side, not the flop side
         assert m.cost["bytes"] > 0
         assert m.cost["flops"] >= 0
-        assert m.cost["roofline_frac"] >= 0
+        # the CPU is not in the peaks table: no roofline share is claimed
+        assert "roofline_frac" not in m.cost
         assert m.mad_ms >= 0.0
 
     def test_tune_workload_publishes_cost_gauges(self, tmp_path):
@@ -343,7 +349,7 @@ class TestCandidateCost:
         byte_series = {k: v for k, v in snap["gauges"].items()
                        if k.startswith("tune.candidate_bytes")}
         roof_series = [k for k in snap["gauges"] if k.startswith("tune.roofline_frac")]
-        assert byte_series and roof_series
+        assert byte_series and not roof_series   # no CPU roofline published
         assert any(v > 0 for v in byte_series.values())
         assert any(f'variant="{entry.variant}"' in k for k in byte_series)
 

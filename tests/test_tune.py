@@ -722,3 +722,27 @@ class TestQuantLayoutTuning:
             FOREST_VARIANTS["forest_fused_speculative_q"] = spec
             registry_fingerprint.cache_clear()
         assert registry_fingerprint() == fp
+
+
+def test_failed_candidate_is_logged_and_counted(caplog):
+    """A candidate that raises is a logged, counted failure with its error —
+    never a silent ∞ that quietly loses to a jnp variant."""
+    import logging
+
+    import jax.numpy as jnp
+
+    from repro import obs
+    from repro.tune.measure import _note_measurements, measure_candidate
+
+    enc = breadth_first_encode(random_tree(n_attrs=19, n_classes=7, max_depth=8, seed=0))
+    assert enc.n_nodes > 256   # the onehot jump's one-hot cannot fit VMEM here
+    rec = jnp.zeros((64, 19), jnp.float32)
+    with caplog.at_level(logging.WARNING, logger="repro.tune.measure"):
+        m = measure_candidate(Candidate.make("pallas_speculative_onehot"), rec, enc,
+                              max_depth=8, warmup=0, iters=1)
+    assert m.failed and "no record tile fits" in m.error
+    assert any("pallas_speculative_onehot" in r.getMessage() for r in caplog.records)
+    reg = obs.Registry()
+    _note_measurements(reg, "tree", [m])
+    counters = obs.snapshot(reg)["counters"]
+    assert counters['tune.failed_candidates{level="tree"}'] == 1
