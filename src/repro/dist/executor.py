@@ -413,8 +413,6 @@ class ShardedForestEvaluator:
         evaluation (chunks are single-use by the streaming contract); pass a
         fresh array — or host data, converted here — per call.
         """
-        if not (isinstance(records, jax.Array) and records.dtype == jnp.float32):
-            records = jnp.asarray(records, jnp.float32)
         self._prepare(records)
         m = records.shape[0]
         self.stats.m_calls.inc()
@@ -425,10 +423,14 @@ class ShardedForestEvaluator:
             # shard_map.  The ForestTunedEvaluator is built once — its
             # internal memo makes steady-state calls (serve waves, stream
             # chunks) pure dict probes, and the fused stacked-kernel
-            # candidate stays in play, same as eval_forest_tuned.
+            # candidate stays in play, same as eval_forest_tuned.  It
+            # copies host records to the device itself (``tune.h2d``).
             with self.tracer.span("kernel.dispatch", cat="kernel",
                                   records=int(m), devices=1):
                 return self._forest_evaluator()(records)
+
+        if not (isinstance(records, jax.Array) and records.dtype == jnp.float32):
+            records = jnp.asarray(records, jnp.float32)
 
         fast = self._fast.get(m)
         if fast is None:

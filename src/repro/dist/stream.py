@@ -36,7 +36,6 @@ import time
 from collections import deque
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
@@ -56,6 +55,8 @@ class StreamStats:
         r = self.registry
         self.m_chunks = r.counter("stream.chunks", "chunks drained")
         self.m_records = r.counter("stream.records", "records streamed")
+        self.m_d2h_bytes = r.counter(
+            "stream.d2h_bytes", "bytes of per-tree classes the drains copied device to host")
         self.m_wall_s = r.counter(
             "stream.wall_s", "submit-first → drain-last seconds, per eval()")
         self.m_chunk_ms = r.histogram(
@@ -138,6 +139,7 @@ class StreamingChunker:
         dspan.set(chunk_ms=round(latency_ms, 3), overlap=round(overlap, 3))
         self.stats.m_chunks.inc()
         self.stats.m_records.inc(n)
+        self.stats.m_d2h_bytes.inc(arr.nbytes)
         self.stats.m_chunk_ms.observe(latency_ms)
         self.stats.m_overlap.observe(overlap)
         self.stats.chunk_ms.append(latency_ms)
@@ -197,13 +199,12 @@ class StreamingChunker:
                               chunk_records=size) as espan:
             for start in range(0, m, size):
                 chunk = rec[start : start + size]
-                # the executor's fused program shards/pads the chunk as part
-                # of its single dispatch, so no explicit device_put hop is
-                # needed — the dispatch (and with it the transfer) is queued
-                # asynchronously
+                # the evaluator copies the host chunk to the device inside its
+                # dispatch (timed there as ``tune.h2d`` on one device), and
+                # the evaluation is queued asynchronously
                 with self.tracer.span("stream.chunk.submit", cat="stream",
                                       chunk=n_chunks, records=chunk.shape[0]):
-                    out = self.evaluator(jnp.asarray(chunk))
+                    out = self.evaluator(chunk)
                 pending.append((out, time.perf_counter(), chunk.shape[0]))
                 n_chunks += 1
                 # submit-before-drain: the new chunk's dispatch is already

@@ -193,6 +193,20 @@ def _votes_body(compute, records_ref, *refs):
         out_ref[...] += votes
 
 
+def kernel_name(algorithm: str, layout: str = "f32", jump_mode: str = "gather",
+                votes: bool = False) -> str:
+    """The kernel's name on the device trace's ops line, e.g.
+    ``tree_eval_speculative_gather`` or ``tree_eval_data_parallel_q_votes``:
+    the algorithm, the jump mode of the f32 speculative kernel, ``_q`` for
+    the quantized tables and ``_votes`` for the vote epilogue."""
+    name = f"tree_eval_{algorithm}"
+    if algorithm == "speculative" and layout == "f32":
+        name += f"_{jump_mode}"
+    if layout == "quant":
+        name += "_q"
+    return name + ("_votes" if votes else "")
+
+
 def forest_pallas(
     records: jax.Array,
     tables: tuple[jax.Array, ...],
@@ -256,4 +270,5 @@ def forest_pallas(
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name=kernel_name(algorithm, layout, jump_mode, votes=n_classes is not None),
     )(records, *tables)

@@ -5,6 +5,13 @@ tree and record arrays, VMEM-budget-driven block-size selection, phantom-node
 padding (the paper's half-warp phantom generalised to 128-lane tiles), the
 interpret-or-compile decision (:func:`pallas_interpret`), and unpadding of
 results.
+
+The tree path and the fused forest paths time their host phases on the
+``tracer`` the caller passes (``kernel.pack``: the tables and the tile;
+``kernel.prep``: sanitising and padding the records; ``kernel.launch``: the
+jitted call and the slice of its output) and count the padding they add to
+the records on ``pad_bytes``, a counter the caller holds
+(``kernel.pad_bytes`` of :mod:`repro.tune.dispatch`).
 """
 
 from __future__ import annotations
@@ -18,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.eval_speculative import sanitize_records
 from repro.core.tree import EncodedTree, attr_select_matrix, pad_tree, tree_depth
 from repro.kernels.tree_eval import kernel as _k
@@ -118,11 +126,25 @@ class PackedTree:
         self.class_val = jnp.asarray(penc.class_val[None, :], jnp.int32)
 
 
-def _pad_records(records: jax.Array, block_m: int, a_pad: int) -> tuple[jax.Array, int]:
-    m, a = records.shape
-    m_pad = _round_up(max(m, 1), block_m)
-    out = jnp.zeros((m_pad, a_pad), records.dtype)
-    out = out.at[:m, :a].set(records)
+def _prep_records(records, algorithm: str, block_m: int, a_pad: int, *,
+                  tracer: obs.Tracer = obs.NULL_TRACER,
+                  pad_bytes=None) -> tuple[jax.Array, int]:
+    """The (M, A) records as the kernels take them (``kernel.prep``): zero-
+    padded to (M rounded up to ``block_m``, ``a_pad``), and for the
+    speculative kernels sanitised first, since they evaluate every node with
+    a records@S product where non-finite attributes would poison whole rows
+    (inf*0 = NaN).  Returns (padded records, M); the padding's bytes are
+    counted on ``pad_bytes`` when one is given."""
+    with tracer.span("kernel.prep", cat="kernel"):
+        records = jnp.asarray(records)
+        if algorithm == "speculative":
+            records = sanitize_records(records)
+        m, a = records.shape
+        m_pad = _round_up(max(m, 1), block_m)
+        out = jnp.zeros((m_pad, a_pad), records.dtype)
+        out = out.at[:m, :a].set(records)
+    if pad_bytes is not None:
+        pad_bytes.inc((m_pad * a_pad - m * a) * out.dtype.itemsize)
     return out, m
 
 
@@ -163,6 +185,8 @@ def tree_eval(
     algorithm: str = "speculative",
     jump_mode: str = "gather",
     block_m: int | None = None,
+    tracer: obs.Tracer = obs.NULL_TRACER,
+    pad_bytes=None,
 ) -> jax.Array:
     """Evaluate a classification tree over a record batch with a TPU kernel.
 
@@ -173,38 +197,38 @@ def tree_eval(
       algorithm: "speculative" (Procedure 4/5) or "data_parallel" (Procedure 3).
       jump_mode: "gather" | "onehot" pointer-jump implementation.
       block_m: records per tile; default = VMEM-model choice.
+      tracer: times the host phases (module docstring).
+      pad_bytes: counter of the padding bytes added to the records, or None.
 
     Returns:
       (M,) int32 class assignments.
     """
-    if isinstance(tree, EncodedTree):
-        if n_attrs is None:
-            n_attrs = int(np.shape(records)[-1])
-        tree = PackedTree(tree, n_attrs)
-    if block_m is None:
-        block_m = choose_block_m(tree.n_nodes, tree.n_attrs_padded, jump_mode=jump_mode)
-    records = jnp.asarray(records)
-    if algorithm == "speculative":
-        # The speculative kernel evaluates every node with a records@S matmul;
-        # non-finite attributes would poison whole rows (inf*0 = NaN).
-        records = sanitize_records(records)
-    padded, m = _pad_records(records, block_m, tree.n_attrs_padded)
+    with tracer.span("kernel.pack", cat="kernel"):
+        if isinstance(tree, EncodedTree):
+            if n_attrs is None:
+                n_attrs = int(np.shape(records)[-1])
+            tree = PackedTree(tree, n_attrs)
+        if block_m is None:
+            block_m = choose_block_m(tree.n_nodes, tree.n_attrs_padded, jump_mode=jump_mode)
+    padded, m = _prep_records(records, algorithm, block_m, tree.n_attrs_padded,
+                              tracer=tracer, pad_bytes=pad_bytes)
     jumps = max(1, math.ceil(math.log2(max(tree.max_depth, 2))))
-    out = _tree_eval_padded(
-        padded,
-        tree.attr_select,
-        tree.attr_idx,
-        tree.threshold,
-        tree.child,
-        tree.class_val,
-        algorithm=algorithm,
-        block_m=block_m,
-        jump_mode=jump_mode,
-        jumps=jumps,
-        max_depth=tree.max_depth,
-        interpret=pallas_interpret(),
-    )
-    return out[:m]
+    with tracer.span("kernel.launch", cat="kernel"):
+        out = _tree_eval_padded(
+            padded,
+            tree.attr_select,
+            tree.attr_idx,
+            tree.threshold,
+            tree.child,
+            tree.class_val,
+            algorithm=algorithm,
+            block_m=block_m,
+            jump_mode=jump_mode,
+            jumps=jumps,
+            max_depth=tree.max_depth,
+            interpret=pallas_interpret(),
+        )
+        return out[:m]
 
 
 def forest_eval(
@@ -295,6 +319,8 @@ def forest_eval_fused(
     algorithm: str = "speculative",
     jump_mode: str = "gather",
     block_m: int | None = None,
+    tracer: obs.Tracer = obs.NULL_TRACER,
+    pad_bytes=None,
 ) -> jax.Array:
     """Evaluate a whole forest with one fused Pallas launch.
 
@@ -305,39 +331,38 @@ def forest_eval_fused(
       algorithm: "speculative" (Procedure 4/5) or "data_parallel" (Procedure 3).
       jump_mode: "gather" | "onehot" pointer-jump implementation.
       block_m: records per tile; default = VMEM-model choice.
+      tracer / pad_bytes: as for :func:`tree_eval`.
 
     Returns:
       (T, M) int32 per-tree class assignments, bit-identical to running
       :func:`tree_eval` tree by tree.
     """
-    if not isinstance(forest, PackedForest):
-        if n_attrs is None:
-            n_attrs = int(np.shape(records)[-1])
-        forest = PackedForest(forest, n_attrs)
-    if block_m is None:
-        block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode=jump_mode)
-    records = jnp.asarray(records)
-    if algorithm == "speculative":
-        # The fused speculative kernel evaluates every node with a per-tree
-        # records@S matmul; non-finite attributes poison rows (inf*0 = NaN).
-        records = sanitize_records(records)
-    padded, m = _pad_records(records, block_m, forest.n_attrs_padded)
+    with tracer.span("kernel.pack", cat="kernel"):
+        if not isinstance(forest, PackedForest):
+            if n_attrs is None:
+                n_attrs = int(np.shape(records)[-1])
+            forest = PackedForest(forest, n_attrs)
+        if block_m is None:
+            block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode=jump_mode)
+    padded, m = _prep_records(records, algorithm, block_m, forest.n_attrs_padded,
+                              tracer=tracer, pad_bytes=pad_bytes)
     jumps = max(1, math.ceil(math.log2(max(forest.max_depth, 2))))
-    out = _forest_eval_padded(
-        padded,
-        forest.attr_select,
-        forest.attr_idx,
-        forest.threshold,
-        forest.child,
-        forest.class_val,
-        algorithm=algorithm,
-        block_m=block_m,
-        jump_mode=jump_mode,
-        jumps=jumps,
-        max_depth=forest.max_depth,
-        interpret=pallas_interpret(),
-    )
-    return out[:, :m]
+    with tracer.span("kernel.launch", cat="kernel"):
+        out = _forest_eval_padded(
+            padded,
+            forest.attr_select,
+            forest.attr_idx,
+            forest.threshold,
+            forest.child,
+            forest.class_val,
+            algorithm=algorithm,
+            block_m=block_m,
+            jump_mode=jump_mode,
+            jumps=jumps,
+            max_depth=forest.max_depth,
+            interpret=pallas_interpret(),
+        )
+        return out[:, :m]
 
 
 @functools.partial(
@@ -374,6 +399,8 @@ def forest_eval_fused_q(
     thr_dtype: str = "bfloat16",
     calibration=None,
     block_m: int | None = None,
+    tracer: obs.Tracer = obs.NULL_TRACER,
+    pad_bytes=None,
 ) -> jax.Array:
     """Evaluate a whole forest with one fused launch over *quantized* tables.
 
@@ -391,37 +418,37 @@ def forest_eval_fused_q(
         cast round-trips exactly, so results are bit-exact for *any* input).
       algorithm: "speculative" (Procedure 4/5) or "data_parallel" (Procedure 3).
       block_m: records per tile; default = VMEM-model choice.
+      tracer / pad_bytes: as for :func:`tree_eval`.
 
     Returns:
       (T, M) int32 per-tree class assignments.
     """
-    if not isinstance(forest, QuantizedForest):
-        if n_attrs is None:
-            n_attrs = int(np.shape(records)[-1])
-        forest = QuantizedForest(
-            forest, n_attrs, thr_dtype=thr_dtype, calibration=calibration
-        )
-    if block_m is None:
-        block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode="gather")
-    records = jnp.asarray(records)
-    if algorithm == "speculative":
-        # the in-kernel one-hot product has the records@S contract (inf*0 = NaN)
-        records = sanitize_records(records)
-    padded, m = _pad_records(records, block_m, forest.n_attrs_padded)
+    with tracer.span("kernel.pack", cat="kernel"):
+        if not isinstance(forest, QuantizedForest):
+            if n_attrs is None:
+                n_attrs = int(np.shape(records)[-1])
+            forest = QuantizedForest(
+                forest, n_attrs, thr_dtype=thr_dtype, calibration=calibration
+            )
+        if block_m is None:
+            block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode="gather")
+    padded, m = _prep_records(records, algorithm, block_m, forest.n_attrs_padded,
+                              tracer=tracer, pad_bytes=pad_bytes)
     jumps = max(1, math.ceil(math.log2(max(forest.max_depth, 2))))
-    out = _quant_forest_eval_padded(
-        padded,
-        forest.attr_idx,
-        forest.threshold,
-        forest.child,
-        forest.class_val,
-        algorithm=algorithm,
-        block_m=block_m,
-        jumps=jumps,
-        max_depth=forest.max_depth,
-        interpret=pallas_interpret(),
-    )
-    return out[:, :m]
+    with tracer.span("kernel.launch", cat="kernel"):
+        out = _quant_forest_eval_padded(
+            padded,
+            forest.attr_idx,
+            forest.threshold,
+            forest.child,
+            forest.class_val,
+            algorithm=algorithm,
+            block_m=block_m,
+            jumps=jumps,
+            max_depth=forest.max_depth,
+            interpret=pallas_interpret(),
+        )
+        return out[:, :m]
 
 
 @functools.partial(
@@ -482,11 +509,7 @@ def forest_votes_fused(
     if block_m is None:
         block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode=jump_mode)
     c_pad = _round_up(max(int(n_classes), 2), LANE)
-    records = jnp.asarray(records)
-    if algorithm == "speculative":
-        # Same records@S contract as forest_eval_fused (inf*0 = NaN).
-        records = sanitize_records(records)
-    padded, m = _pad_records(records, block_m, forest.n_attrs_padded)
+    padded, m = _prep_records(records, algorithm, block_m, forest.n_attrs_padded)
     jumps = max(1, math.ceil(math.log2(max(forest.max_depth, 2))))
     out = _forest_votes_padded(
         padded,
@@ -513,11 +536,15 @@ def forest_votes_fused(
 # Every registered variant is a semantically identical evaluator of the
 # branchless encoded tree with a uniform calling convention:
 #
-#     fn(records, enc: EncodedTree, *, max_depth: int, **params) -> (M,) int32
+#     fn(records, enc: EncodedTree, *, max_depth: int, tracer=NULL_TRACER,
+#        pad_bytes=None, **params) -> (M,) int32
 #
 # ``params`` only ever contains keys named in ``tunables``; the tuner
 # enumerates (variant × parameter grid) candidates from this table and the
-# dispatch layer replays the winning entry.
+# dispatch layer replays the winning entry.  ``tracer`` and ``pad_bytes``
+# are the dispatching evaluator's (see the module docstring); the Pallas
+# variants time and count with them and the jnp variants drop them with the
+# other keywords they do not read.
 
 
 @dataclasses.dataclass(frozen=True)
@@ -572,7 +599,8 @@ def list_variants(*, engine: str | None = None, algorithm: str | None = None) ->
 
 
 def _pallas_fn(algorithm: str, jump_mode: str) -> Callable:
-    def fn(records, enc, *, max_depth=None, **params):
+    def fn(records, enc, *, max_depth=None, tracer=obs.NULL_TRACER, pad_bytes=None,
+           **params):
         del max_depth  # PackedTree derives it from the encoding
         return tree_eval(
             records,
@@ -580,6 +608,8 @@ def _pallas_fn(algorithm: str, jump_mode: str) -> Callable:
             algorithm=algorithm,
             jump_mode=jump_mode,
             block_m=params.get("block_m"),
+            tracer=tracer,
+            pad_bytes=pad_bytes,
         )
 
     return fn
@@ -650,7 +680,8 @@ register_variant(
 # A *forest* variant evaluates all T trees of a stacked forest at once with a
 # uniform calling convention:
 #
-#     fn(records, forest, *, max_depth: int, **params) -> (T, M) int32
+#     fn(records, forest, *, max_depth: int, tracer=NULL_TRACER, pad_bytes=None,
+#        **params) -> (T, M) int32
 #
 # where ``forest`` is an EncodedForest (or PackedForest for the fused
 # family).  Two families are registered here; the third family the forest
@@ -766,7 +797,8 @@ def _vmap_data_parallel_fn(records, forest, *, max_depth, **params):
 
 
 def _fused_fn(algorithm: str, jump_mode: str) -> Callable:
-    def fn(records, forest, *, max_depth=None, **params):
+    def fn(records, forest, *, max_depth=None, tracer=obs.NULL_TRACER, pad_bytes=None,
+           **params):
         del max_depth  # PackedForest derives it from the encodings
         return forest_eval_fused(
             records,
@@ -774,13 +806,16 @@ def _fused_fn(algorithm: str, jump_mode: str) -> Callable:
             algorithm=algorithm,
             jump_mode=jump_mode,
             block_m=params.get("block_m"),
+            tracer=tracer,
+            pad_bytes=pad_bytes,
         )
 
     return fn
 
 
 def _fused_q_fn(algorithm: str) -> Callable:
-    def fn(records, forest, *, max_depth=None, **params):
+    def fn(records, forest, *, max_depth=None, tracer=obs.NULL_TRACER, pad_bytes=None,
+           **params):
         del max_depth  # QuantizedForest derives it from the encodings
         return forest_eval_fused_q(
             records,
@@ -788,6 +823,8 @@ def _fused_q_fn(algorithm: str) -> Callable:
             algorithm=algorithm,
             thr_dtype=params.get("thr_dtype", "bfloat16"),
             block_m=params.get("block_m"),
+            tracer=tracer,
+            pad_bytes=pad_bytes,
         )
 
     return fn

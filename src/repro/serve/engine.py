@@ -400,6 +400,9 @@ class _ClassifierStatsBase:
         self.m_pad_fraction = r.histogram(
             "serve.pad_fraction", "padding rows / bucket rows per wave",
             ("engine",), boundaries=obs.DEFAULT_RATIO_BOUNDARIES).labels(**lbl)
+        self.m_d2h_bytes = r.counter(
+            "serve.d2h_bytes", "bytes of classes copied device to host",
+            ("engine",)).labels(**lbl)
 
     def wave_ms(self, bucket: str) -> obs.Histogram:
         """The wave-latency histogram series for one shape bucket."""
@@ -537,45 +540,53 @@ class TreeServeEngine:
 
     def _run_wave(self, wave: list[TreeRequest], total: int) -> None:
         t_wave = time.perf_counter()
-        for r in wave:
-            enq = getattr(r, "_t_enqueue", None)
-            if enq is not None:
-                self.stats.m_queue_wait_ms.observe((t_wave - enq) * 1e3)
-        self.stats.m_waves.inc()
-        self.stats.m_records.inc(total)
-        batch = np.concatenate([r.records for r in wave], axis=0).astype(np.float32)
-        shape = self._shape_of(batch, self.tree, self._eval.depth)
-        key = shape.key()
-        bucket_m = shape.bucket().m
-        self.stats.m_padded_slots.inc(bucket_m - total)
-        self.stats.m_pad_fraction.observe((bucket_m - total) / max(bucket_m, 1))
         with self.tracer.span("serve.wave", cat="serve", engine="tree",
-                              requests=len(wave), records=total, bucket=key):
+                              requests=len(wave), records=total) as wspan:
+            for r in wave:
+                enq = getattr(r, "_t_enqueue", None)
+                if enq is not None:
+                    self.stats.m_queue_wait_ms.observe((t_wave - enq) * 1e3)
+            self.stats.m_waves.inc()
+            self.stats.m_records.inc(total)
+            with self.tracer.span("serve.batch", cat="serve", records=total):
+                batch = np.concatenate([r.records for r in wave], axis=0).astype(np.float32)
+            shape = self._shape_of(batch, self.tree, self._eval.depth)
+            key = shape.key()
+            wspan.set(bucket=key)
+            bucket_m = shape.bucket().m
+            self.stats.m_padded_slots.inc(bucket_m - total)
+            self.stats.m_pad_fraction.observe((bucket_m - total) / max(bucket_m, 1))
             t0 = time.perf_counter()
             try:
                 with self.tracer.span("kernel.dispatch", cat="kernel", bucket=key):
-                    out = np.asarray(jax.block_until_ready(self._eval(batch)))
+                    out = self._eval(batch)
+                    with self.tracer.span("kernel.wait", cat="kernel"):
+                        jax.block_until_ready(out)
+                    with self.tracer.span("kernel.d2h", cat="kernel"):
+                        out = np.asarray(out)
             except BaseException as exc:
                 if self.flight is not None:
                     self.flight.note_exception(exc)
                 raise
             dt = time.perf_counter() - t0
-        self.stats.m_eval_s.inc(dt)
-        self.stats.wave_ms(key).observe(dt * 1e3)
-        if self.flight is not None:
-            self.flight.note_wave(latency_ms=dt * 1e3, bucket=key,
-                                  records=total, requests=len(wave))
-        off = 0
-        for r in wave:
-            m = r.records.shape[0]
-            r.out = out[off:off + m]
-            r.done = True
-            off += m
-        self.stats.note_bucket_wave(key)
-        if self.profiler is not None:
-            self.profiler.note_wave(key, batch)
-        if self.retuner is not None:
-            self.retuner.note(key, batch)
+            off = 0
+            for r in wave:
+                m = r.records.shape[0]
+                r.out = out[off:off + m]
+                r.done = True
+                off += m
+            with self.tracer.span("serve.hooks", cat="serve"):
+                self.stats.m_d2h_bytes.inc(out.nbytes)
+                self.stats.m_eval_s.inc(dt)
+                self.stats.wave_ms(key).observe(dt * 1e3)
+                if self.flight is not None:
+                    self.flight.note_wave(latency_ms=dt * 1e3, bucket=key,
+                                          records=total, requests=len(wave))
+                self.stats.note_bucket_wave(key)
+                if self.profiler is not None:
+                    self.profiler.note_wave(key, batch)
+                if self.retuner is not None:
+                    self.retuner.note(key, batch)
 
     def dump_flight(self, reason: str = "manual"):
         """Write a flight-recorder debug bundle now; returns its path.
@@ -629,6 +640,9 @@ class ForestEngineStats(_ClassifierStatsBase):
             ("engine",)).labels(**lbl)
         self.m_chunk_ms = r.histogram(
             "serve.chunk_ms", "per-chunk latency", ("engine",)).labels(**lbl)
+        self.m_h2d_bytes = r.counter(
+            "serve.h2d_bytes", "bytes of per-tree classes copied host to device for the vote",
+            ("engine",)).labels(**lbl)
         self.m_anytime_waves = r.counter(
             "serve.anytime.waves", "waves served through the anytime cascade")
         self.m_anytime_truncations = r.counter(
@@ -797,19 +811,20 @@ class ForestServeEngine:
 
     def _run_wave_inner(self, wave: list[TreeRequest], total: int) -> None:
         t_wave = time.perf_counter()
-        for r in wave:
-            enq = getattr(r, "_t_enqueue", None)
-            if enq is not None:
-                self.stats.m_queue_wait_ms.observe((t_wave - enq) * 1e3)
-        self.stats.m_waves.inc()
-        self.stats.m_records.inc(total)
-        batch = np.concatenate([r.records for r in wave], axis=0).astype(np.float32)
         wspan = self.tracer.span(
             "serve.wave", cat="serve", engine="forest",
             requests=len(wave), records=total,
             mode="anytime" if self.anytime is not None else "stream",
         )
         with wspan:
+            for r in wave:
+                enq = getattr(r, "_t_enqueue", None)
+                if enq is not None:
+                    self.stats.m_queue_wait_ms.observe((t_wave - enq) * 1e3)
+            self.stats.m_waves.inc()
+            self.stats.m_records.inc(total)
+            with self.tracer.span("serve.batch", cat="serve", records=total):
+                batch = np.concatenate([r.records for r in wave], axis=0).astype(np.float32)
             if self.anytime is not None:
                 # anytime path: the cascade owns staging/early exit, so the
                 # wave bypasses the chunker — the SLO check needs whole-stage
@@ -857,6 +872,8 @@ class ForestServeEngine:
                     with self.tracer.span("serve.vote", cat="serve", records=total):
                         out = np.asarray(
                             majority_vote(jnp.asarray(per_tree), self.n_classes))
+                    self.stats.m_h2d_bytes.inc(per_tree.nbytes)
+                    self.stats.m_d2h_bytes.inc(out.nbytes)
                 else:
                     out = per_tree
                 dt = time.perf_counter() - t0
@@ -869,18 +886,19 @@ class ForestServeEngine:
                     off += m
             key = self._eval._forest_evaluator().shape_of(batch).key()
             wspan.set(bucket=key)
-        self.stats.wave_ms(key).observe(dt * 1e3)
-        self.stats.note_bucket_wave(key)
-        if self.flight is not None:
-            self.flight.note_wave(
-                latency_ms=dt * 1e3, bucket=key, records=total,
-                requests=len(wave),
-                mode="anytime" if self.anytime is not None else "stream",
-            )
-        if self.profiler is not None:
-            self.profiler.note_wave(key, batch)
-        if self.retuner is not None:
-            self.retuner.note(key, batch)
+            with self.tracer.span("serve.hooks", cat="serve"):
+                self.stats.wave_ms(key).observe(dt * 1e3)
+                self.stats.note_bucket_wave(key)
+                if self.flight is not None:
+                    self.flight.note_wave(
+                        latency_ms=dt * 1e3, bucket=key, records=total,
+                        requests=len(wave),
+                        mode="anytime" if self.anytime is not None else "stream",
+                    )
+                if self.profiler is not None:
+                    self.profiler.note_wave(key, batch)
+                if self.retuner is not None:
+                    self.retuner.note(key, batch)
 
     def dump_flight(self, reason: str = "manual"):
         """Write a flight-recorder debug bundle now; returns its path.

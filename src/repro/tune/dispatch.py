@@ -20,6 +20,12 @@ candidate space spans three families (per-tree variant vectors, shared-
 variant vmap, fused stacked kernel).  Both evaluators expose ``promote`` /
 ``invalidate`` — the atomic winner-swap hooks the serve engines' background
 re-tune drives.
+
+A call is timed on the evaluator's tracer in phases: ``tune.h2d`` (host
+records copied to the device), ``tune.resolve`` (a fast-path miss),
+``tune.pad`` (bucket padding) and ``tune.variant`` (the variant call up to
+its asynchronous return, which times its own host phases as ``kernel.*``
+spans).
 """
 
 from __future__ import annotations
@@ -106,6 +112,28 @@ class _TuneObs:
             "tune.survival_provenance",
             "cascade-survival provenance at class-level resolutions",
             ("source",))
+        self.h2d_bytes = r.counter(
+            "tune.h2d_bytes", "bytes of records copied host to device")
+        self.pad_bytes = r.counter(
+            "kernel.pad_bytes",
+            "bytes of padding the kernels add to their records (attributes and tile rows)")
+
+    def to_device(self, records) -> jax.Array:
+        """``records`` as a float32 device array; a copy from the host is
+        timed (``tune.h2d``) and counted (``tune.h2d_bytes``)."""
+        if isinstance(records, jax.Array):
+            return records if records.dtype == jnp.float32 else records.astype(jnp.float32)
+        with self.tracer.span("tune.h2d", cat="tune"):
+            records = jnp.asarray(records, jnp.float32)
+        self.h2d_bytes.inc(records.nbytes)
+        return records
+
+    def pad(self, records, bucket_m: int):
+        """``bucket_pad_records`` timed as ``tune.pad`` where it pads."""
+        if records.shape[0] == bucket_m:
+            return records
+        with self.tracer.span("tune.pad", cat="tune", bucket_m=bucket_m):
+            return bucket_pad_records(records, bucket_m)
 
     def note_resolution(self, level: str, source: str) -> None:
         self.resolutions.labels(level=level, source=source).inc()
@@ -308,27 +336,26 @@ class TunedEvaluator:
         """Evaluate the tree over ``records`` (M, A) → (M,) int32 classes,
         through the bucket's resolved variant (bucket-padded, unpadded on
         return); bit-identical to ``eval_serial`` for every resolution."""
-        if not (isinstance(records, jax.Array) and records.dtype == jnp.float32):
-            records = jnp.asarray(records, jnp.float32)
+        tob = self._obs
+        records = tob.to_device(records)
         m, a = records.shape
         fast = self._fast.get((m, a))
         if fast is None:
-            gen = self._gen
-            cand, _ = self.resolve(records)
-            spec = get_variant(cand.variant)
-            bucket_m = WorkloadShape(m, self.enc.n_nodes, a, self.depth).bucket().m
-            fast = (spec, cand.param_dict, bucket_m)
-            with self._swap_lock:
-                if gen == self._gen:   # don't cache a pre-swap resolution
-                    self._fast[(m, a)] = fast
+            with tob.tracer.span("tune.resolve", cat="tune"):
+                gen = self._gen
+                cand, _ = self.resolve(records)
+                spec = get_variant(cand.variant)
+                bucket_m = WorkloadShape(m, self.enc.n_nodes, a, self.depth).bucket().m
+                fast = (spec, cand.param_dict, bucket_m)
+                with self._swap_lock:
+                    if gen == self._gen:   # don't cache a pre-swap resolution
+                        self._fast[(m, a)] = fast
         spec, params, bucket_m = fast
-        out = spec.fn(
-            bucket_pad_records(records, bucket_m),
-            self.enc,
-            max_depth=self.depth,
-            **params,
-        )
-        return out if out.shape[0] == m else out[:m]
+        records = tob.pad(records, bucket_m)
+        with tob.tracer.span("tune.variant", cat="tune", variant=spec.name):
+            out = spec.fn(records, self.enc, max_depth=self.depth, tracer=tob.tracer,
+                          pad_bytes=tob.pad_bytes, **params)
+            return out if out.shape[0] == m else out[:m]
 
 
 def tuned_eval(
@@ -590,6 +617,7 @@ class ForestTunedEvaluator:
         if cand.variant == PER_TREE_FAMILY:
             evs = self._tree_evaluators()
             return lambda rec: jnp.stack([ev(rec) for ev in evs])
+        tob = self._obs
         spec = get_forest_variant(cand.variant)
         params = cand.param_dict
         depth = max(int(self.forest.max_depth), 1)
@@ -614,24 +642,27 @@ class ForestTunedEvaluator:
             target = self.forest
 
         def run(rec):
-            out = spec.fn(bucket_pad_records(rec, bucket_m), target, max_depth=depth, **params)
-            return out if out.shape[1] == m else out[:, :m]
+            rec = tob.pad(rec, bucket_m)
+            with tob.tracer.span("tune.variant", cat="tune", variant=spec.name):
+                out = spec.fn(rec, target, max_depth=depth, tracer=tob.tracer,
+                              pad_bytes=tob.pad_bytes, **params)
+                return out if out.shape[1] == m else out[:, :m]
 
         return run
 
     def __call__(self, records) -> jax.Array:
         """Per-tree class assignments, shape (T, M) int32."""
-        if not (isinstance(records, jax.Array) and records.dtype == jnp.float32):
-            records = jnp.asarray(records, jnp.float32)
+        records = self._obs.to_device(records)
         m, a = records.shape
         run = self._fast.get((m, a))
         if run is None:
-            gen = self._gen
-            cand, _ = self.resolve(records)
-            run = self._runner(cand, m, a)
-            with self._swap_lock:
-                if gen == self._gen:   # don't cache a pre-swap resolution
-                    self._fast[(m, a)] = run
+            with self._obs.tracer.span("tune.resolve", cat="tune"):
+                gen = self._gen
+                cand, _ = self.resolve(records)
+                run = self._runner(cand, m, a)
+                with self._swap_lock:
+                    if gen == self._gen:   # don't cache a pre-swap resolution
+                        self._fast[(m, a)] = run
         return run(records)
 
     # -- class-level dispatch (majority vote vs early-exit cascade) ---------
@@ -754,8 +785,7 @@ class ForestTunedEvaluator:
         resolution picked.  Both are exact, so the output always equals
         ``majority_vote(self(records), n_classes)``.
         """
-        if not (isinstance(records, jax.Array) and records.dtype == jnp.float32):
-            records = jnp.asarray(records, jnp.float32)
+        records = self._obs.to_device(records)
         m, a = records.shape
         key = ("cls", m, a, int(n_classes))
         run = self._fast.get(key)
