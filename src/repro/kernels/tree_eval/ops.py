@@ -6,10 +6,16 @@ padding (the paper's half-warp phantom generalised to 128-lane tiles), the
 interpret-or-compile decision (:func:`pallas_interpret`), and unpadding of
 results.
 
+The node tables are packed once (:class:`PackedTree`, :class:`PackedForest`,
+``QuantizedForest``) and reused across calls; the records are sanitised
+(speculative kernels), padded to whole tiles and 128 lanes, and the output
+sliced back inside each kernel's jitted program (:func:`_pad_records`), so a
+call on packed tables is one jitted call and no eager device work.
+
 The tree path and the fused forest paths time their host phases on the
-``tracer`` the caller passes (``kernel.pack``: the tables and the tile;
-``kernel.prep``: sanitising and padding the records; ``kernel.launch``: the
-jitted call and the slice of its output) and count the padding they add to
+``tracer`` the caller passes (``kernel.pack``: tables packed here, only when
+the caller hands over an encoding rather than packed tables;
+``kernel.launch``: the jitted call) and count the padding the kernels add to
 the records on ``pad_bytes``, a counter the caller holds
 (``kernel.pad_bytes`` of :mod:`repro.tune.dispatch`).
 """
@@ -105,6 +111,11 @@ def block_m_fits(n_nodes: int, n_attrs: int, *, jump_mode: str = "gather") -> bo
     return vmem_bytes(SUBLANE, n_nodes, n_attrs, jump_mode=jump_mode) <= VMEM_BUDGET
 
 
+def _pointer_jumps(max_depth: int) -> int:
+    """Pointer-jump rounds that resolve a path of ``max_depth`` hops."""
+    return max(1, math.ceil(math.log2(max(max_depth, 2))))
+
+
 class PackedTree:
     """Device-ready padded tree tables for the kernels."""
 
@@ -126,26 +137,27 @@ class PackedTree:
         self.class_val = jnp.asarray(penc.class_val[None, :], jnp.int32)
 
 
-def _prep_records(records, algorithm: str, block_m: int, a_pad: int, *,
-                  tracer: obs.Tracer = obs.NULL_TRACER,
-                  pad_bytes=None) -> tuple[jax.Array, int]:
-    """The (M, A) records as the kernels take them (``kernel.prep``): zero-
-    padded to (M rounded up to ``block_m``, ``a_pad``), and for the
-    speculative kernels sanitised first, since they evaluate every node with
-    a records@S product where non-finite attributes would poison whole rows
-    (inf*0 = NaN).  Returns (padded records, M); the padding's bytes are
-    counted on ``pad_bytes`` when one is given."""
-    with tracer.span("kernel.prep", cat="kernel"):
-        records = jnp.asarray(records)
-        if algorithm == "speculative":
-            records = sanitize_records(records)
-        m, a = records.shape
-        m_pad = _round_up(max(m, 1), block_m)
-        out = jnp.zeros((m_pad, a_pad), records.dtype)
-        out = out.at[:m, :a].set(records)
-    if pad_bytes is not None:
-        pad_bytes.inc((m_pad * a_pad - m * a) * out.dtype.itemsize)
-    return out, m
+def _pad_records(records, *, algorithm: str, block_m: int, a_pad: int) -> jax.Array:
+    """The (M, A) records as the kernels take them, traced inside each
+    jitted entry point: zero-padded to (M rounded up to ``block_m``,
+    ``a_pad``), and for the speculative kernels sanitised first, since they
+    evaluate every node with a records@S product where non-finite
+    attributes would poison whole rows (inf*0 = NaN)."""
+    if algorithm == "speculative":
+        records = sanitize_records(records)
+    m, a = records.shape
+    return jnp.pad(records, ((0, _round_up(max(m, 1), block_m) - m), (0, a_pad - a)))
+
+
+def _count_padding(records, algorithm: str, block_m: int, a_pad: int, pad_bytes) -> None:
+    """Count on ``pad_bytes`` (when given) the bytes :func:`_pad_records`
+    adds to ``records``, from their shape alone."""
+    if pad_bytes is None:
+        return
+    m, a = np.shape(records)
+    dtype = np.float32 if algorithm == "speculative" else getattr(records, "dtype", np.float32)
+    itemsize = jax.dtypes.canonicalize_dtype(dtype).itemsize
+    pad_bytes.inc((_round_up(max(m, 1), block_m) * a_pad - m * a) * itemsize)
 
 
 @functools.partial(
@@ -167,14 +179,18 @@ def _tree_eval_padded(
     max_depth: int,
     interpret: bool,
 ):
-    """One tree ((1, N) tables, (A, N) ``attr_select``) as a T = 1 forest."""
+    """One tree ((1, N) tables, (A_pad, N) ``attr_select``) as a T = 1
+    forest over (M, A) records, padded here and sliced back to M."""
+    m = records.shape[0]
+    padded = _pad_records(records, algorithm=algorithm, block_m=block_m,
+                          a_pad=attr_select.shape[-2])
     first = attr_select[None] if algorithm == "speculative" else attr_idx
     out = _k.forest_pallas(
-        records, (first, threshold, child, class_val),
+        padded, (first, threshold, child, class_val),
         algorithm=algorithm, block_m=block_m, interpret=interpret,
         total_jumps=jumps, jump_mode=jump_mode, max_depth=max_depth,
     )
-    return out[0, :, 0]
+    return out[0, :m, 0]
 
 
 def tree_eval(
@@ -192,7 +208,7 @@ def tree_eval(
 
     Args:
       records: (M, A) float array (any float dtype; compared in f32).
-      tree: an :class:`EncodedTree` (padded internally) or prebuilt
+      tree: an :class:`EncodedTree` (packed here, every call) or prebuilt
         :class:`PackedTree`.
       algorithm: "speculative" (Procedure 4/5) or "data_parallel" (Procedure 3).
       jump_mode: "gather" | "onehot" pointer-jump implementation.
@@ -203,19 +219,17 @@ def tree_eval(
     Returns:
       (M,) int32 class assignments.
     """
-    with tracer.span("kernel.pack", cat="kernel"):
-        if isinstance(tree, EncodedTree):
+    if isinstance(tree, EncodedTree):
+        with tracer.span("kernel.pack", cat="kernel"):
             if n_attrs is None:
                 n_attrs = int(np.shape(records)[-1])
             tree = PackedTree(tree, n_attrs)
-        if block_m is None:
-            block_m = choose_block_m(tree.n_nodes, tree.n_attrs_padded, jump_mode=jump_mode)
-    padded, m = _prep_records(records, algorithm, block_m, tree.n_attrs_padded,
-                              tracer=tracer, pad_bytes=pad_bytes)
-    jumps = max(1, math.ceil(math.log2(max(tree.max_depth, 2))))
+    if block_m is None:
+        block_m = choose_block_m(tree.n_nodes, tree.n_attrs_padded, jump_mode=jump_mode)
+    _count_padding(records, algorithm, block_m, tree.n_attrs_padded, pad_bytes)
     with tracer.span("kernel.launch", cat="kernel"):
-        out = _tree_eval_padded(
-            padded,
+        return _tree_eval_padded(
+            records,
             tree.attr_select,
             tree.attr_idx,
             tree.threshold,
@@ -224,11 +238,10 @@ def tree_eval(
             algorithm=algorithm,
             block_m=block_m,
             jump_mode=jump_mode,
-            jumps=jumps,
+            jumps=_pointer_jumps(tree.max_depth),
             max_depth=tree.max_depth,
             interpret=pallas_interpret(),
         )
-        return out[:m]
 
 
 def forest_eval(
@@ -302,13 +315,16 @@ def _forest_eval_padded(
     max_depth: int,
     interpret: bool,
 ):
+    m = records.shape[0]
+    padded = _pad_records(records, algorithm=algorithm, block_m=block_m,
+                          a_pad=attr_select.shape[-2])
     first = attr_select if algorithm == "speculative" else attr_idx
     out = _k.forest_pallas(
-        records, (first, threshold, child, class_val),
+        padded, (first, threshold, child, class_val),
         algorithm=algorithm, block_m=block_m, interpret=interpret,
         total_jumps=jumps, jump_mode=jump_mode, max_depth=max_depth,
     )
-    return out[:, :, 0]
+    return out[:, :m, 0]
 
 
 def forest_eval_fused(
@@ -337,19 +353,17 @@ def forest_eval_fused(
       (T, M) int32 per-tree class assignments, bit-identical to running
       :func:`tree_eval` tree by tree.
     """
-    with tracer.span("kernel.pack", cat="kernel"):
-        if not isinstance(forest, PackedForest):
+    if not isinstance(forest, PackedForest):
+        with tracer.span("kernel.pack", cat="kernel"):
             if n_attrs is None:
                 n_attrs = int(np.shape(records)[-1])
             forest = PackedForest(forest, n_attrs)
-        if block_m is None:
-            block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode=jump_mode)
-    padded, m = _prep_records(records, algorithm, block_m, forest.n_attrs_padded,
-                              tracer=tracer, pad_bytes=pad_bytes)
-    jumps = max(1, math.ceil(math.log2(max(forest.max_depth, 2))))
+    if block_m is None:
+        block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode=jump_mode)
+    _count_padding(records, algorithm, block_m, forest.n_attrs_padded, pad_bytes)
     with tracer.span("kernel.launch", cat="kernel"):
-        out = _forest_eval_padded(
-            padded,
+        return _forest_eval_padded(
+            records,
             forest.attr_select,
             forest.attr_idx,
             forest.threshold,
@@ -358,11 +372,10 @@ def forest_eval_fused(
             algorithm=algorithm,
             block_m=block_m,
             jump_mode=jump_mode,
-            jumps=jumps,
+            jumps=_pointer_jumps(forest.max_depth),
             max_depth=forest.max_depth,
             interpret=pallas_interpret(),
         )
-        return out[:, :m]
 
 
 @functools.partial(
@@ -382,12 +395,15 @@ def _quant_forest_eval_padded(
     max_depth: int,
     interpret: bool,
 ):
+    m, a = records.shape
+    padded = _pad_records(records, algorithm=algorithm, block_m=block_m,
+                          a_pad=_round_up(a, LANE))
     out = _k.forest_pallas(
-        records, (attr_idx, threshold, child, class_val),
+        padded, (attr_idx, threshold, child, class_val),
         algorithm=algorithm, layout="quant", block_m=block_m, interpret=interpret,
         total_jumps=jumps, max_depth=max_depth,
     )
-    return out[:, :, 0]
+    return out[:, :m, 0]
 
 
 def forest_eval_fused_q(
@@ -423,38 +439,35 @@ def forest_eval_fused_q(
     Returns:
       (T, M) int32 per-tree class assignments.
     """
-    with tracer.span("kernel.pack", cat="kernel"):
-        if not isinstance(forest, QuantizedForest):
+    if not isinstance(forest, QuantizedForest):
+        with tracer.span("kernel.pack", cat="kernel"):
             if n_attrs is None:
                 n_attrs = int(np.shape(records)[-1])
             forest = QuantizedForest(
                 forest, n_attrs, thr_dtype=thr_dtype, calibration=calibration
             )
-        if block_m is None:
-            block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode="gather")
-    padded, m = _prep_records(records, algorithm, block_m, forest.n_attrs_padded,
-                              tracer=tracer, pad_bytes=pad_bytes)
-    jumps = max(1, math.ceil(math.log2(max(forest.max_depth, 2))))
+    if block_m is None:
+        block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode="gather")
+    _count_padding(records, algorithm, block_m, forest.n_attrs_padded, pad_bytes)
     with tracer.span("kernel.launch", cat="kernel"):
-        out = _quant_forest_eval_padded(
-            padded,
+        return _quant_forest_eval_padded(
+            records,
             forest.attr_idx,
             forest.threshold,
             forest.child,
             forest.class_val,
             algorithm=algorithm,
             block_m=block_m,
-            jumps=jumps,
+            jumps=_pointer_jumps(forest.max_depth),
             max_depth=forest.max_depth,
             interpret=pallas_interpret(),
         )
-        return out[:, :m]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "algorithm", "block_m", "jump_mode", "jumps", "max_depth", "c_pad", "interpret",
+        "algorithm", "block_m", "jump_mode", "jumps", "max_depth", "n_classes", "interpret",
     ),
 )
 def _forest_votes_padded(
@@ -470,15 +483,20 @@ def _forest_votes_padded(
     jump_mode: str,
     jumps: int,
     max_depth: int,
-    c_pad: int,
+    n_classes: int,
     interpret: bool,
 ):
+    m = records.shape[0]
+    padded = _pad_records(records, algorithm=algorithm, block_m=block_m,
+                          a_pad=attr_select.shape[-2])
     first = attr_select if algorithm == "speculative" else attr_idx
-    return _k.forest_pallas(
-        records, (first, threshold, child, class_val),
-        algorithm=algorithm, block_m=block_m, interpret=interpret, n_classes=c_pad,
+    out = _k.forest_pallas(
+        padded, (first, threshold, child, class_val),
+        algorithm=algorithm, block_m=block_m, interpret=interpret,
+        n_classes=_round_up(max(n_classes, 2), LANE),
         total_jumps=jumps, jump_mode=jump_mode, max_depth=max_depth,
     )
+    return out[:m, :n_classes]
 
 
 def forest_votes_fused(
@@ -508,11 +526,8 @@ def forest_votes_fused(
         forest = PackedForest(forest, n_attrs)
     if block_m is None:
         block_m = choose_block_m(forest.n_nodes, forest.n_attrs_padded, jump_mode=jump_mode)
-    c_pad = _round_up(max(int(n_classes), 2), LANE)
-    padded, m = _prep_records(records, algorithm, block_m, forest.n_attrs_padded)
-    jumps = max(1, math.ceil(math.log2(max(forest.max_depth, 2))))
-    out = _forest_votes_padded(
-        padded,
+    return _forest_votes_padded(
+        records,
         forest.attr_select,
         forest.attr_idx,
         forest.threshold,
@@ -521,12 +536,11 @@ def forest_votes_fused(
         algorithm=algorithm,
         block_m=block_m,
         jump_mode=jump_mode,
-        jumps=jumps,
+        jumps=_pointer_jumps(forest.max_depth),
         max_depth=forest.max_depth,
-        c_pad=c_pad,
+        n_classes=int(n_classes),
         interpret=pallas_interpret(),
     )
-    return out[:m, :n_classes]
 
 
 # ---------------------------------------------------------------------------

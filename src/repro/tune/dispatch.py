@@ -21,11 +21,15 @@ variant vmap, fused stacked kernel).  Both evaluators expose ``promote`` /
 ``invalidate`` — the atomic winner-swap hooks the serve engines' background
 re-tune drives.
 
+Both evaluators pack a Pallas winner's node tables once per record width
+when its bucket resolves and hand the packed tables to the variant, so a
+steady-state call is one jitted call on prepared tables.
+
 A call is timed on the evaluator's tracer in phases: ``tune.h2d`` (host
-records copied to the device), ``tune.resolve`` (a fast-path miss),
-``tune.pad`` (bucket padding) and ``tune.variant`` (the variant call up to
-its asynchronous return, which times its own host phases as ``kernel.*``
-spans).
+records copied to the device), ``tune.resolve`` (a fast-path miss, with
+``kernel.pack`` inside it where it packs tables), ``tune.pad`` (bucket
+padding) and ``tune.variant`` (the variant call up to its asynchronous
+return, which times its jitted call as ``kernel.launch``).
 """
 
 from __future__ import annotations
@@ -48,6 +52,8 @@ from repro.kernels.tree_eval.ops import (
     PER_TREE_FAMILY,
     VARIANTS,
     PackedForest,
+    PackedTree,
+    choose_block_m,
     get_forest_variant,
     get_variant,
 )
@@ -117,6 +123,8 @@ class _TuneObs:
         self.pad_bytes = r.counter(
             "kernel.pad_bytes",
             "bytes of padding the kernels add to their records (attributes and tile rows)")
+        self.packs = r.counter(
+            "kernel.packs", "node-table packs built on the dispatch path", ("level",))
 
     def to_device(self, records) -> jax.Array:
         """``records`` as a float32 device array; a copy from the host is
@@ -134,6 +142,14 @@ class _TuneObs:
             return records
         with self.tracer.span("tune.pad", cat="tune", bucket_m=bucket_m):
             return bucket_pad_records(records, bucket_m)
+
+    def pack(self, level: str, build):
+        """``build()``, the node tables of a resolved winner, timed as
+        ``kernel.pack`` and counted on ``kernel.packs``."""
+        with self.tracer.span("kernel.pack", cat="kernel", level=level):
+            packed = build()
+        self.packs.labels(level=level).inc()
+        return packed
 
     def note_resolution(self, level: str, source: str) -> None:
         self.resolutions.labels(level=level, source=source).inc()
@@ -228,9 +244,12 @@ class TunedEvaluator:
         self.heuristic_kw = dict(heuristic_kw or {})
         self.depth = max(tree_depth(enc), 1)
         self._resolved: dict[str, tuple[Candidate, str]] = {}
-        # (M, A) → (spec, params, bucket_m): the steady-state call path does
-        # one dict probe and zero array ops beyond the kernel itself.
+        # (M, A) → (spec, params, bucket_m, tables): the steady-state call
+        # path does one dict probe and one jitted call.
         self._fast: dict[tuple[int, int], tuple] = {}
+        # A → the Pallas variants' tables; they depend on the tree and the
+        # width alone, so a winner swap never rebuilds them
+        self._packed: dict[int, PackedTree] = {}
         # guards promote()/invalidate() against the resolve path; the fast
         # path itself stays lock-free (GIL-atomic dict probes).  _gen counts
         # swaps so a runner built from a pre-swap resolution is never cached
@@ -332,6 +351,29 @@ class TunedEvaluator:
             resolved = self._resolved.setdefault(key, (cand, source))
         return resolved[0], source
 
+    def _tables(self, a: int) -> PackedTree:
+        """The Pallas variants' tables at width ``a``, packed on first use."""
+        packed = self._packed.get(a)
+        if packed is None:
+            packed = self._packed.setdefault(
+                a, self._obs.pack("tree", lambda: PackedTree(self.enc, a)))
+        return packed
+
+    def _fast_entry(self, cand: Candidate, m: int, a: int) -> tuple:
+        """(spec, params, bucket_m, tables) for one resolved candidate: a
+        Pallas variant gets the packed tables and its tile, resolved here; a
+        jnp variant gets the encoding."""
+        spec = get_variant(cand.variant)
+        params = cand.param_dict
+        bucket_m = WorkloadShape(m, self.enc.n_nodes, a, self.depth).bucket().m
+        if spec.engine != "pallas":
+            return spec, params, bucket_m, self.enc
+        packed = self._tables(a)
+        if params.get("block_m") is None:
+            params = dict(params, block_m=choose_block_m(
+                packed.n_nodes, packed.n_attrs_padded, jump_mode=spec.jump_mode))
+        return spec, params, bucket_m, packed
+
     def __call__(self, records) -> jax.Array:
         """Evaluate the tree over ``records`` (M, A) → (M,) int32 classes,
         through the bucket's resolved variant (bucket-padded, unpadded on
@@ -344,16 +386,14 @@ class TunedEvaluator:
             with tob.tracer.span("tune.resolve", cat="tune"):
                 gen = self._gen
                 cand, _ = self.resolve(records)
-                spec = get_variant(cand.variant)
-                bucket_m = WorkloadShape(m, self.enc.n_nodes, a, self.depth).bucket().m
-                fast = (spec, cand.param_dict, bucket_m)
+                fast = self._fast_entry(cand, m, a)
                 with self._swap_lock:
                     if gen == self._gen:   # don't cache a pre-swap resolution
                         self._fast[(m, a)] = fast
-        spec, params, bucket_m = fast
+        spec, params, bucket_m, tables = fast
         records = tob.pad(records, bucket_m)
         with tob.tracer.span("tune.variant", cat="tune", variant=spec.name):
-            out = spec.fn(records, self.enc, max_depth=self.depth, tracer=tob.tracer,
+            out = spec.fn(records, tables, max_depth=self.depth, tracer=tob.tracer,
                           pad_bytes=tob.pad_bytes, **params)
             return out if out.shape[0] == m else out[:m]
 
@@ -631,12 +671,13 @@ class ForestTunedEvaluator:
             # threshold dtype is part of the pack, so the memo keys on it.
             qkey = (a, params.get("thr_dtype", "bfloat16"))
             if self._quant is None or self._quant_key != qkey:
-                self._quant = QuantizedForest(self.forest, a, thr_dtype=qkey[1])
+                self._quant = tob.pack("forest", lambda: QuantizedForest(
+                    self.forest, a, thr_dtype=qkey[1]))
                 self._quant_key = qkey
             target = self._quant
         elif spec.family == "fused":
             if self._packed is None or self._packed.n_attrs != a:
-                self._packed = PackedForest(self.forest, a)
+                self._packed = tob.pack("forest", lambda: PackedForest(self.forest, a))
             target = self._packed
         else:
             target = self.forest

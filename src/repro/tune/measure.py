@@ -21,6 +21,7 @@ from repro.kernels.tree_eval.cascade import MAJORITY_FAMILY, get_cascade_variant
 from repro.kernels.tree_eval.ops import (
     PER_TREE_FAMILY,
     PackedForest,
+    PackedTree,
     get_forest_variant,
     get_variant,
 )
@@ -291,10 +292,14 @@ def measure_candidate(
     spec = get_variant(candidate.variant)
     params = candidate.param_dict
 
-    def fn(rec):
-        return spec.fn(rec, enc, max_depth=max_depth, **params)
-
     try:
+        # a Pallas variant gets its tables packed here, outside the timed
+        # region, as dispatch packs them once per evaluator
+        target = PackedTree(enc, records.shape[1]) if spec.engine == "pallas" else enc
+
+        def fn(rec):
+            return spec.fn(rec, target, max_depth=max_depth, **params)
+
         samples = time_callable(lambda: fn(records), warmup=warmup, iters=iters)
     except Exception as exc:
         return _failed("tree", candidate, exc)

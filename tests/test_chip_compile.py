@@ -3,11 +3,12 @@
 No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached, from shapes alone.  Interpret mode cannot catch
 what these catch — block shapes the tiling refuses, gathers Mosaic cannot
-lower, tiles that overflow VMEM.  Each jitted padded entry point of
-``ops.py`` is compiled with ``interpret=False`` at the paper's M = 65,536
-records, A = 19 attributes padded to 128, and both node widths the serving
-paths use: N = 128 (a depth ≤ 7 tree) and N = 512 (depth 8).  The record
-tile is the one ``choose_block_m`` picks, so the VMEM model is checked too.
+lower, tiles that overflow VMEM.  Each jitted entry point of ``ops.py`` is
+compiled with ``interpret=False`` at the paper's M = 65,536 records of
+A = 19 attributes, unpadded (the entry point pads them to 128 lanes inside
+its program), and both node widths the serving paths use: N = 128 (a
+depth ≤ 7 tree) and N = 512 (depth 8).  The record tile is the one
+``choose_block_m`` picks, so the VMEM model is checked too.
 
 The topology is described inside a module fixture, never at import time:
 only one process may hold the TPU library, and every test worker imports
@@ -23,7 +24,8 @@ import jax.numpy as jnp
 
 from repro.kernels.tree_eval import ops
 
-M, A = 65_536, 128
+M, A = 65_536, 19
+A_PAD = 128
 WIDTHS = [128, 512]
 T_FOREST = 64
 # (algorithm, jump_mode) of the class and vote kernels
@@ -49,10 +51,10 @@ def _spec(sharding, shape, dtype=jnp.float32):
 
 def _block_m_or_refused(n: int, jump_mode: str):
     """The tile the model picks, or None where no tile fits (asserted)."""
-    if ops.block_m_fits(n, A, jump_mode=jump_mode):
-        return ops.choose_block_m(n, A, jump_mode=jump_mode)
+    if ops.block_m_fits(n, A_PAD, jump_mode=jump_mode):
+        return ops.choose_block_m(n, A_PAD, jump_mode=jump_mode)
     with pytest.raises(ValueError, match="no record tile fits"):
-        ops.choose_block_m(n, A, jump_mode=jump_mode)
+        ops.choose_block_m(n, A_PAD, jump_mode=jump_mode)
     return None
 
 
@@ -64,9 +66,9 @@ def _compile(fn, *args, **static) -> str:
 
 def _f32_tables(sharding, t: int | None, n: int):
     """(attr_select, attr_idx, threshold, child, class_val) shapes; t=None
-    is the single-tree layout ((A, N) and (1, N))."""
+    is the single-tree layout ((A_PAD, N) and (1, N))."""
     lead = (1,) if t is None else (t,)
-    sel = (A, n) if t is None else (t, A, n)
+    sel = (A_PAD, n) if t is None else (t, A_PAD, n)
     return (
         _spec(sharding, sel),
         _spec(sharding, lead + (n,), jnp.int32),
@@ -106,7 +108,7 @@ def test_vote_kernel_compiles(one_chip, algorithm, jump_mode, n):
     _compile(ops._forest_votes_padded, _spec(one_chip, (M, A)),
              *_f32_tables(one_chip, T_FOREST, n),
              algorithm=algorithm, block_m=bm, jump_mode=jump_mode, jumps=3, max_depth=8,
-             c_pad=128)
+             n_classes=7)
 
 
 @pytest.mark.parametrize("n", WIDTHS)
@@ -117,4 +119,4 @@ def test_quant_kernel_compiles(one_chip, algorithm, n):
     dtypes = (jnp.int8, jnp.bfloat16, jnp.int16, jnp.int8)
     tables = [_spec(one_chip, (T_FOREST, n), d) for d in dtypes]
     _compile(ops._quant_forest_eval_padded, _spec(one_chip, (M, A)), *tables,
-             algorithm=algorithm, block_m=ops.choose_block_m(n, A), jumps=3, max_depth=8)
+             algorithm=algorithm, block_m=ops.choose_block_m(n, A_PAD), jumps=3, max_depth=8)

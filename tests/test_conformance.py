@@ -36,8 +36,13 @@ from repro.kernels.tree_eval.ops import (
     FOREST_VARIANTS,
     LANE,
     VARIANTS,
+    PackedForest,
+    PackedTree,
     _round_up,
     block_m_fits,
+    forest_eval_fused,
+    forest_votes_fused,
+    tree_eval,
 )
 from repro.kernels.tree_eval.quant import THR_DTYPES, QuantizedForest
 from repro.kernels.tree_eval.ref import forest_eval_ref, tree_eval_ref
@@ -244,6 +249,50 @@ def test_quantized_forest_split_safe_conforms(thr_dtype):
     )
     got = forest_eval_fused_q(jnp.asarray(RECORDS), qf)
     _assert_exact(got, FOREST_REF, f"quant-split-safe/{thr_dtype}")
+
+
+# ---------------------------------------------------------------------------
+# The padding traced into each jitted entry point: ragged M (never a whole
+# tile), A off the 128-lane grid, non-finite attributes — every kernel path
+# on prebuilt tables, against the serial reference
+# ---------------------------------------------------------------------------
+
+def _padding_case(m: int, a: int):
+    """Three trees over ``a`` attributes and (m, a) records whose first rows
+    carry NaN, ±inf and mixed non-finite attributes."""
+    trees = [breadth_first_encode(random_tree(n_attrs=a, n_classes=N_CLASSES,
+                                              max_depth=d, seed=a + d))
+             for d in (2, 5, 7)]
+    rec = np.random.default_rng(m + a).normal(size=(m, a)).astype(np.float32)
+    rec[0, ::3], rec[0, 1::3], rec[0, 2::3] = np.nan, np.inf, -np.inf
+    rec[1:2, :] = np.inf
+    rec[2:3, :] = -np.inf
+    rec[3:4, :] = np.nan
+    return trees, rec
+
+
+@pytest.mark.parametrize("a", [19, 130])
+@pytest.mark.parametrize("m", [1, 200, 1000])
+@pytest.mark.parametrize("algorithm", ["speculative", "data_parallel"])
+@pytest.mark.parametrize("path", ["tree", "forest", "quant", "votes"])
+def test_jitted_padding_conforms(path, algorithm, m, a):
+    trees, rec = _padding_case(m, a)
+    want = np.stack([eval_serial(t, rec) for t in trees])      # (T, M)
+    forest = EncodedForest(trees)
+    label = f"{path}/{algorithm}/m={m}/a={a}"
+    if path == "tree":
+        got = np.stack([tree_eval(jnp.asarray(rec), PackedTree(t, a), algorithm=algorithm)
+                        for t in trees])
+    elif path == "forest":
+        got = forest_eval_fused(jnp.asarray(rec), PackedForest(forest, a), algorithm=algorithm)
+    elif path == "quant":
+        got = forest_eval_fused_q(jnp.asarray(rec), QuantizedForest(forest, a),
+                                  algorithm=algorithm)
+    else:
+        got = forest_votes_fused(jnp.asarray(rec), PackedForest(forest, a),
+                                 n_classes=N_CLASSES, algorithm=algorithm)
+        want = np.stack([np.bincount(col, minlength=N_CLASSES) for col in want.T])
+    _assert_exact(got, want.astype(np.int32), label)
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,10 @@
 """The request path's phase spans and transfer counters.
 
 A traced ``TreeServeEngine`` wave must split ``kernel.dispatch`` into its
-phases (the host-to-device copy, resolution, bucket padding, the variant
-call with the kernel's own host phases, the device wait and the copy back)
-and frame it with ``serve.batch`` and ``serve.hooks`` inside ``serve.wave``;
+phases (the host-to-device copy, resolution with the one pack of the tree's
+tables, bucket padding, the variant call with its one jitted call, the
+device wait and the copy back) and frame it with ``serve.batch`` and
+``serve.hooks`` inside ``serve.wave``;
 the byte counters must equal their closed forms; an untraced engine must
 record nothing.  The forest engine's wave carries the tune layer's spans.
 """
@@ -29,8 +30,7 @@ TREE_PARENT = {
     "tune.variant": "kernel.dispatch",
     "kernel.wait": "kernel.dispatch",
     "kernel.d2h": "kernel.dispatch",
-    "kernel.pack": "tune.variant",
-    "kernel.prep": "tune.variant",
+    "kernel.pack": "tune.resolve",
     "kernel.launch": "tune.variant",
 }
 # siblings in the order the request path runs them
@@ -38,7 +38,6 @@ TREE_ORDER = [
     ("serve.wave", ["serve.batch", "kernel.dispatch", "serve.hooks"]),
     ("kernel.dispatch", ["tune.h2d", "tune.resolve", "tune.pad", "tune.variant",
                          "kernel.wait", "kernel.d2h"]),
-    ("tune.variant", ["kernel.pack", "kernel.prep", "kernel.launch"]),
 ]
 
 
@@ -100,10 +99,15 @@ def test_steady_state_wave_has_no_resolve_or_pad(tmp_path):
     eng = _tree_engine(tmp_path, tracer=tracer)
     _one_wave(eng, [256])           # a full bucket: nothing to pad
     tracer.clear()
-    _one_wave(eng, [256])           # the fast path: nothing to resolve
+    _one_wave(eng, [256])           # the fast path: nothing to resolve or pack
     spans = _spans(tracer)
     assert "tune.resolve" not in spans and "tune.pad" not in spans
-    assert set(spans) == set(TREE_PARENT) - {"tune.resolve", "tune.pad"} | {"serve.wave"}
+    assert set(spans) == (set(TREE_PARENT) - {"tune.resolve", "tune.pad", "kernel.pack"}
+                          | {"serve.wave"})
+    # the variant call is the one jitted call and nothing else
+    assert [e.name for e in tracer.events()
+            if _inside((e.ts_us, e.ts_us + e.dur_us), spans["tune.variant"])
+            and e.name != "tune.variant"] == ["kernel.launch"]
 
 
 def test_transfer_and_padding_counters_have_closed_forms(tmp_path):
@@ -122,6 +126,8 @@ def test_transfer_and_padding_counters_have_closed_forms(tmp_path):
     # the kernel pads the bucket's rows to whole tiles and A to 128 lanes
     assert counters["kernel.pad_bytes"] == (m_pad * 128 - bucket_m * N_ATTRS) * 4
     assert counters['serve.d2h_bytes{engine="tree"}'] == m * 4
+    # the tree's tables were packed once, when the bucket resolved
+    assert counters['kernel.packs{level="tree"}'] == 1
 
 
 @pytest.mark.parametrize("tracer", [None, obs.Tracer(enabled=False)],

@@ -10,7 +10,9 @@ import json
 import numpy as np
 import pytest
 
-from repro.core import Node, breadth_first_encode, eval_serial, paper_tree, random_tree
+from repro.core import (
+    Node, breadth_first_encode, eval_serial, paper_tree, random_tree, tree_depth,
+)
 from repro.core.analysis import CostModel, speculative_wins
 from repro.core.forest import EncodedForest
 from repro.kernels.tree_eval import (
@@ -406,6 +408,85 @@ class TestDispatch:
         assert c.param_dict == {"jumps_per_round": 3}
         # frozen/hashable: usable as dict keys in resolution memos
         assert hash(c) == hash(Candidate.make("jnp_speculative_gather", jumps_per_round=3))
+
+
+# ---------------------------------------------------------------------------
+# Pack once: a Pallas winner's tables are built when its bucket resolves
+# ---------------------------------------------------------------------------
+
+
+def _packs(registry, level):
+    from repro import obs
+
+    return obs.snapshot(registry)["counters"].get(f'kernel.packs{{level="{level}"}}', 0)
+
+
+class TestPackOnce:
+    def test_tree_tables_packed_once_per_width(self, tmp_path):
+        from repro import obs
+
+        registry = obs.Registry()
+        enc = breadth_first_encode(paper_tree())
+        ev = TunedEvaluator(enc, cache=TuneCache(tmp_path / "c.json"),
+                            engines=("pallas",), registry=registry)
+
+        def check(rec):
+            assert np.array_equal(np.asarray(ev(rec)), eval_serial(enc, rec))
+
+        for seed in range(5):                       # same shape: one pack
+            check(_records(64, 19, seed=seed))
+        check(_records(200, 19, seed=5))            # another bucket, same width
+        assert _packs(registry, "tree") == 1
+        check(_records(64, 23, seed=6))             # a new width packs again
+        assert _packs(registry, "tree") == 2
+
+        # a winner swap re-resolves the bucket but reuses the tables
+        rec = _records(64, 19, seed=7)
+        cand, _ = ev.resolve(rec)
+        other = next(s.name for s in VARIANTS.values()
+                     if s.engine == "pallas" and s.name != cand.variant)
+        ev.promote(WorkloadShape.of(rec, enc).key(), Candidate.make(other))
+        check(rec)
+        assert ev.resolve(rec)[0].variant == other
+        assert _packs(registry, "tree") == 2
+
+    def test_forest_tables_packed_once(self, tmp_path):
+        from repro import obs
+
+        registry = obs.Registry()
+        forest = EncodedForest([
+            breadth_first_encode(random_tree(n_attrs=9, n_classes=6, max_depth=d, seed=d))
+            for d in (3, 6)
+        ])
+        ev = ForestTunedEvaluator(forest, cache=TuneCache(tmp_path / "c.json"),
+                                  families=("fused",), registry=registry)
+        for seed in range(4):
+            rec = _records(100, 9, seed=seed)
+            ref = np.stack([eval_serial(forest.tree(i), rec) for i in range(2)])
+            assert np.array_equal(np.asarray(ev(rec)), ref)
+        assert _packs(registry, "forest") == 1
+        assert _packs(registry, "tree") == 0
+
+    def test_measure_candidate_packs_once_per_candidate(self, monkeypatch):
+        from repro.kernels.tree_eval import ops
+        from repro.tune.measure import measure_candidate
+
+        built = []
+        init = ops.PackedTree.__init__
+
+        def counting_init(self, *args, **kw):
+            built.append(1)
+            init(self, *args, **kw)
+
+        monkeypatch.setattr(ops.PackedTree, "__init__", counting_init)
+        enc = breadth_first_encode(paper_tree())
+        rec = _records(64, 19, seed=11)
+        for name in sorted(s.name for s in VARIANTS.values() if s.engine == "pallas"):
+            built.clear()
+            m = measure_candidate(Candidate.make(name), rec, enc,
+                                  max_depth=tree_depth(enc), warmup=2, iters=3)
+            assert not m.failed, m.error
+            assert len(built) == 1, name                # not one per timed call
 
 
 # ---------------------------------------------------------------------------
