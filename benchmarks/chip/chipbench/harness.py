@@ -288,9 +288,11 @@ def serve_one(adapter, uid: int, records: np.ndarray, served: Served, trace: boo
 
 def warm_up(adapter, source, cell: Cell, seed: int, compiles: Compiles) -> dict:
     """Waves of the cell's own shapes until the hot-bucket re-tune has run
-    and drained and ``SETTLE_WAVES`` waves in a row compiled nothing."""
+    and drained, one whole block of the mix's sizes has been served, and
+    ``SETTLE_WAVES`` waves in a row compiled nothing."""
     n_max = MAX_WARMUP_WAVES
     sizes = traffic.warmup_sizes(cell.mix, seed, n_max)
+    n_min = traffic.block_len(cell.mix["records"])
     n = 0
     split = {}
 
@@ -314,7 +316,7 @@ def warm_up(adapter, source, cell: Cell, seed: int, compiles: Compiles) -> dict:
     split["retune_drain_s"] = time.perf_counter() - t
     t = time.perf_counter()
     quiet = 0
-    while quiet < SETTLE_WAVES and n < n_max:
+    while (quiet < SETTLE_WAVES or n < n_min) and n < n_max:
         before = compiles.count
         one()
         quiet = quiet + 1 if compiles.count == before else 0

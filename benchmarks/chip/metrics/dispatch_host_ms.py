@@ -4,11 +4,10 @@ the program's ``kernel.dispatch`` span.
 The ``kernel.dispatch`` time that no ``tune.h2d`` (the host-to-device copy
 of the records, up to its asynchronous return), ``kernel.wait`` (the wait
 for the device, and for the copy to land) and ``kernel.d2h`` (the copy
-back) covers: the dispatch's fast path, the bucket padding, packing the
-tree, preparing the records and the jitted call.  These are eager JAX
-operations, which the profiler slows: in a traced run this reads about half
-again the profiler-off time.  None where the program does not split its
-dispatch into these spans.
+back) covers: the dispatch's fast path, the bucket padding and the jitted
+call.  These are eager JAX operations, which the profiler slows: in a
+traced run this reads about half again the profiler-off time.  None where
+the program does not split its dispatch into these spans.
 """
 
 

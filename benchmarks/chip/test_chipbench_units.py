@@ -4,6 +4,7 @@ the TPU library."""
 
 from __future__ import annotations
 
+import hashlib
 import json
 import math
 import os
@@ -33,15 +34,110 @@ def test_open_loop_plan_is_uniform_and_fixed_by_the_rate():
 
 
 def test_closed_loop_plan_and_seeded_check_sample():
-    mix = {"loop": "closed", "pool": 6, "check_requests": 2,
-           "records": {"dist": "log_uniform", "lo": 1, "hi": 1024}}
+    mix = {"loop": "closed", "pool": 32, "check_requests": 2,
+           "records": {"dist": "log_blocks", "lo": 1, "hi": 1024, "block": 16}}
     p = traffic.plan(mix, 3, 10.0)
-    assert p.due_s is None and len(p.sizes) == 6
+    assert p.due_s is None and len(p.sizes) == 32
     assert all(1 <= n <= 1024 for n in p.sizes)
     assert p == traffic.plan(mix, 3, 10.0)
     pos = traffic.check_positions(p, 3, 40)
     assert len(pos) == 2 and pos == traffic.check_positions(p, 3, 40)
     assert traffic.check_positions(p, 3, 1) == [0]
+
+
+LOG_BLOCKS = {"dist": "log_blocks", "lo": 1, "hi": 1024, "block": 16}
+BLOCK = [1, 2, 3, 4, 6, 10, 16, 25, 40, 64, 102, 161, 256, 406, 645, 1024]
+
+
+@pytest.mark.parametrize("loop", ["open", "closed"])
+def test_log_blocks_give_every_seed_the_same_sizes_in_every_whole_block(loop):
+    assert traffic.block_sizes(LOG_BLOCKS) == BLOCK and sum(BLOCK) == 2765
+    mix = {"loop": loop, "rate_per_s": 100.0, "pool": 512, "records": LOG_BLOCKS}
+    a = traffic.plan(mix, 2**31 + 1001, 5.0).sizes
+    b = traffic.plan(mix, 2**31 + 1002, 5.0).sizes
+    n = len(a)        # open: the 500 sends, the last block partial; closed: the pool
+    assert n == len(b) == (500 if loop == "open" else 512) and a != b   # order: the seed's
+    for i in range(0, n - 15, 16):
+        assert sorted(a[i:i + 16]) == sorted(b[i:i + 16]) == BLOCK
+    gap = np.abs(np.cumsum(a) - np.cumsum(b))
+    assert gap.max() < sum(BLOCK)
+    assert gap[np.arange(15, n, 16)].max() == 0           # whole blocks agree exactly
+
+
+@pytest.mark.parametrize("loop", ["open", "closed"])
+def test_a_pool_of_broken_blocks_is_refused(loop):
+    mix = {"loop": loop, "rate_per_s": 100.0, "pool": 40, "records": LOG_BLOCKS}
+    with pytest.raises(ValueError, match="not a multiple of block 16"):
+        traffic.plan(mix, 7, 1.0)
+
+
+def test_log_uniform_sizes_are_refused_with_the_reason():
+    mix = {"loop": "closed", "pool": 16, "records": {"dist": "log_uniform", "lo": 1,
+                                                     "hi": 1024}}
+    with pytest.raises(ValueError, match="depend on the seed.*'log_blocks'"):
+        traffic.plan(mix, 7, 1.0)
+
+
+def test_warm_up_sizes_hold_every_size_in_whole_blocks_largest_first():
+    mix = {"loop": "open", "rate_per_s": 100.0, "pool": 512, "records": LOG_BLOCKS}
+    w = traffic.warmup_sizes(mix, 2**31 + 5, 256)
+    assert w[0] == 1024 and sorted(w[:16]) == BLOCK
+    assert all(sorted(w[i:i + 16]) == BLOCK for i in range(0, 256, 16))
+    fixed = dict(mix, records={"dist": "fixed", "n": 300})
+    assert traffic.warmup_sizes(fixed, 5, 4) == [300] * 4
+
+
+def test_warm_up_serves_one_whole_block_before_it_settles():
+    class Quiet:
+        """An engine that compiles nothing and keeps the sizes it served."""
+
+        engine = None
+
+        def __init__(self):
+            self.served = []
+
+        def serve(self, uid, records):
+            self.served.append(records.shape[0])
+
+        def counter(self, prefix):
+            return 0
+
+        def drain(self):
+            pass
+
+    class Zeros:
+        def draw(self, gen, n):
+            return np.zeros((n, 2), np.float32)
+
+    def cell(spec):
+        mix = {"loop": "open", "rate_per_s": 1.0, "pool": 16, "records": spec}
+        return harness.Cell("c", 1, {}, mix, (), ())
+
+    a = Quiet()
+    harness.warm_up(a, Zeros(), cell(LOG_BLOCKS), 11, harness.Compiles())
+    assert sorted(a.served) == BLOCK                      # nothing compiles: one block
+    b = Quiet()
+    harness.warm_up(b, Zeros(), cell({"dist": "fixed", "n": 8}), 11, harness.Compiles())
+    assert b.served == [8] * (1 + harness.SETTLE_WAVES)
+
+
+def test_the_stream_plan_and_records_are_those_pinned_before_log_blocks():
+    """seg_cart.stream's sizes, due times, warm-up sizes and first request,
+    hashed: fixed sizes draw exactly what they drew before ``log_blocks``."""
+    cell = harness.load_cell(ROOT, "seg_cart.stream")
+    seed = 2**31 + 4242
+    p = traffic.plan(cell.mix, seed, 30.0)
+    src = harness.load_part("generators", "segmentation_twin").Source(cell.config)
+    first = src.draw(traffic.rng(seed, traffic.WINDOW, 0), p.sizes[0])
+    h = hashlib.sha256()
+    h.update(np.asarray(p.sizes, np.int64).tobytes())
+    h.update(np.asarray(p.due_s, np.float64).tobytes())
+    h.update(np.asarray(traffic.warmup_sizes(cell.mix, seed, harness.MAX_WARMUP_WAVES),
+                        np.int64).tobytes())
+    h.update(np.ascontiguousarray(first).tobytes())
+    assert (len(p.sizes), len(p.due_s), first.shape) == (64, 1920, (65536, 19))
+    assert h.hexdigest() == \
+        "31164306920e36303c287d9720cd806cdf631ee71df8a8ce474ecb3de3a41d22"
 
 
 def test_the_same_seed_gives_the_same_records():

@@ -11,6 +11,14 @@ the backlog does not grow: the mean latency of the window's last quarter
 exceeds that of its first quarter by less than two service times, and p95
 stays under five service times.  Each line of output is one JSON object; the knee is written into
 the traffic file by hand, as a number, at 4/5 of the highest sustained rate.
+
+Every plan here goes through ``chipbench.traffic.plan``, so a mix of
+variable sizes sends ``log_blocks``: each block of consecutive requests
+holds the same sizes for every seed, only their order drawn from it, and
+rates measured on different seeds count the same records.  Sizes drawn one
+by one would move a rate by the seed's share of large requests, not by
+what the server sustains.  The closed loop's pool is the least whole
+number of blocks that holds 8 requests.
 """
 
 from __future__ import annotations
@@ -66,7 +74,8 @@ def main(argv=None) -> int:
         sess = harness.prepare(cell, args.seed, cache_path=os.path.join(tmp, "tune.json"),
                                compiles=compiles, trace=False)
         print(json.dumps({"setup": sess.split}), flush=True)
-        closed = dict(cell.mix, loop="closed", pool=8)
+        block = traffic.block_len(cell.mix["records"])
+        closed = dict(cell.mix, loop="closed", pool=-(-8 // block) * block)
         plan = traffic.plan(closed, args.seed, args.seconds)
         served, start = harness.window(sess.adapter, plan, sess.requests(plan),
                                        args.seconds, False)
